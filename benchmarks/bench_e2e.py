@@ -550,6 +550,8 @@ def smoke(rate_rps: float = 60.0, n_requests: int = 16) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core.compile_cache import use_checkout_compile_cache
+    use_checkout_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--smoke", action="store_true",
                         help="tiny CI run; nonzero exit on regression "

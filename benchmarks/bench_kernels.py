@@ -39,8 +39,8 @@ def run() -> None:
 
     # decode attention: B8 S4096 cache
     B, S = 8, 4096
-    kc = jax.random.normal(ks[1], (B, S, Hkv, D), jnp.float32)
-    vc = jax.random.normal(ks[2], (B, S, Hkv, D), jnp.float32)
+    kc = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32)   # head-major
+    vc = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32)
     qd = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
     f = jax.jit(lambda q, k, v: ref.decode_attention(q, k, v, S))
     dt = _time(f, qd, kc, vc)
@@ -54,10 +54,13 @@ def run() -> None:
     perm = jax.random.permutation(ks[2], B * max_pages) + 1
     table = perm.reshape(B, max_pages).astype(jnp.int32)
     P = 1 + B * max_pages
-    kp = jnp.zeros((P, page_size, Hkv, D), jnp.float32).at[table.reshape(-1)].set(
-        kc.reshape(B * max_pages, page_size, Hkv, D))
-    vp = jnp.zeros((P, page_size, Hkv, D), jnp.float32).at[table.reshape(-1)].set(
-        vc.reshape(B * max_pages, page_size, Hkv, D))
+
+    def paged(cache):                # [B, Hkv, S, D] -> [P, Hkv, page_size, D]
+        rows = jnp.swapaxes(cache.reshape(B, Hkv, max_pages, page_size, D), 1, 2)
+        return jnp.zeros((P, Hkv, page_size, D), jnp.float32).at[
+            table.reshape(-1)].set(rows.reshape(B * max_pages, Hkv, page_size, D))
+
+    kp, vp = paged(kc), paged(vc)
     lengths = jnp.full((B,), S, jnp.int32)
     f = jax.jit(lambda q, k, v, t, ln: ref.paged_decode_attention(q, k, v, t, ln))
     dt_paged = _time(f, qd, kp, vp, table, lengths)

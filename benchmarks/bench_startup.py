@@ -189,12 +189,12 @@ def run(gw, light_requests: int = 10, heavy_requests: int = 2) -> None:
 
     # heavyweight paths (the Docker tier) — few samples, they cost seconds each.
     # cold_jit_cached = re-trace + XLA persistent disk cache hit (the gVisor tier);
-    # cold_jit = full recompile with the disk cache OFF (the full Docker stack).
-    from pathlib import Path
+    # cold_jit = full recompile, the driver keeps the disk cache out (the full
+    # Docker stack).
+    import jax
 
     from repro.core.compile_cache import disable_xla_disk_cache, enable_xla_disk_cache
 
-    # cold_jit FIRST (before any persistent cache exists — clean full compiles)
     label = "fig1:cold_jit:p1"
     for _ in range(heavy_requests):
         gw.invoke(spec.name, driver="cold_jit", label=label)
@@ -202,7 +202,7 @@ def run(gw, light_requests: int = 10, heavy_requests: int = 2) -> None:
     emit("startup/cold_jit/par1", st.p50 * 1e3, f"p99_ms={st.p99:.2f};n={st.n}")
     stage_breakdown(gw, label, "cold_jit")
 
-    enable_xla_disk_cache(Path(gw.work_dir) / "xla_disk_cache")
+    previous = enable_xla_disk_cache()
     gw.invoke(spec.name, driver="cold_jit_cached", label="cache_warmup")  # populate
     label = "fig1:cold_jit_cached:p1"
     for _ in range(heavy_requests):
@@ -210,12 +210,11 @@ def run(gw, light_requests: int = 10, heavy_requests: int = 2) -> None:
     st = gw.stats(label, "startup")
     emit("startup/cold_jit_cached/par1", st.p50 * 1e3, f"p99_ms={st.p99:.2f};n={st.n}")
     stage_breakdown(gw, label, "cold_jit_cached")
-    disable_xla_disk_cache()
+    disable_xla_disk_cache(previous)
 
     # loader comparison: snapshot (pre-laid-out) vs generic checkpoint
     import time
 
-    import jax
     from repro.core.snapshot import load_generic_checkpoint
 
     t0 = time.perf_counter()

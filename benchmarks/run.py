@@ -25,6 +25,8 @@ from benchmarks.common import ROWS, emit  # noqa: E402
 
 
 def main() -> None:
+    from repro.core.compile_cache import use_checkout_compile_cache
+    use_checkout_compile_cache()
     print("name,us_per_call,derived")
 
     bench_kernels.run()

@@ -50,6 +50,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.core.compile_cache import (CompileCache, persistent_cache_off,
+                                      refuse_degrade_on_tpu)
 from repro.core.executor import Executor, ReadinessGates, SplitServe
 from repro.core.metrics import Timeline, now
 
@@ -223,7 +225,8 @@ class FetchProgramHead(FetchProgram):
     Sets ``ctx.split_program`` so Finalize knows to wrap the head in a
     ``SplitServe`` and to acquire the tail/fused programs in the background.
     Any failure on the split path degrades to the fused program — the split
-    is a latency optimization, never a correctness dependency.
+    is a latency optimization, never a correctness dependency — except on a
+    TPU, where the failure raises rather than hide a broken device path.
     """
 
     def __init__(self) -> None:
@@ -248,7 +251,8 @@ class FetchProgramHead(FetchProgram):
             return
         try:
             super().run(ctx)
-        except Exception:
+        except Exception as e:
+            refuse_degrade_on_tpu("fetching the head sub-program", e)
             # degrade: forget any half-acquired head artifact, refetch fused
             self._split = False
             ctx.program = ctx.program_payload = ctx.program_entry = None
@@ -277,16 +281,26 @@ class DeserializeProgram(Stage):
 
 
 class TraceCompile(Stage):
-    """The Docker-stack tier: re-trace and (disk-cache permitting) re-compile."""
+    """The Docker-stack tier: re-trace and re-compile. ``disk_cache=False``
+    keeps the compile out of JAX's persistent cache (a full recompile);
+    with True it may be a disk hit, as the process's cache settings allow."""
 
     name = "trace_compile"
     track = TRACK_PROGRAM
 
+    def __init__(self, disk_cache: bool = True) -> None:
+        self.disk_cache = disk_cache
+
     def run(self, ctx: BootContext) -> None:
         dep = ctx.dep
         fresh = jax.jit(lambda p, t: dep.serve_fn(p, t))   # fresh identity => re-trace
-        ctx.program = fresh.lower(dep.abstract_params,
-                                  dep.abstract_tokens_for(ctx.bucket_rows)).compile()
+        lowered = fresh.lower(dep.abstract_params,
+                              dep.abstract_tokens_for(ctx.bucket_rows))
+        if self.disk_cache:
+            ctx.program = lowered.compile()
+        else:
+            with persistent_cache_off():
+                ctx.program = lowered.compile()
 
 
 class RestoreWeightsHost(Stage):
@@ -558,7 +572,6 @@ def _acquire_program(cache, key: str,
     """Load an executable through the host program tier when one is attached
     (tier hit may be pre-linked; misses park the loaded executable back on the
     tier entry for the next boot), else deserialize the payload directly."""
-    from repro.core.compile_cache import CompileCache
     if cache is not None:
         entry = cache.get("program", key)
         if entry is None:

@@ -10,9 +10,11 @@ Layout on disk (content-addressed by FunctionSpec.cache_key):
     <root>/<key>/program.bin     pickled (serialized_executable, in_tree, out_tree)
     <root>/<key>/manifest.json   ImageManifest
 
-Also exposes :func:`enable_xla_disk_cache` — the XLA persistent compilation cache,
-which is the ``cold_jit_cached`` (gVisor-tier) path: still re-traces, but the XLA
-compile itself becomes a disk hit.
+Also places JAX's persistent compilation cache (:func:`use_checkout_compile_cache`)
+and exposes :func:`enable_xla_disk_cache`, which caches every compile there — the
+``cold_jit_cached`` (gVisor-tier) path: still re-traces, but the XLA compile
+itself becomes a disk hit — and :func:`persistent_cache_off`, which keeps the
+``cold_jit`` tier's compiles out of it.
 
 Invariants: ``put_compiled`` publishes atomically (a concurrent reader sees
 the old blob or the new one, never a torn write); payload bytes are immutable
@@ -21,6 +23,7 @@ on byte-identical content per key.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import pickle
 import shutil
@@ -30,6 +33,7 @@ from typing import Callable
 
 import jax
 from jax.experimental import serialize_executable as _se
+from jax.experimental.compilation_cache import compilation_cache as _jax_cc
 
 from repro.core.artifact import ImageManifest
 
@@ -137,13 +141,101 @@ class CompileCache:
         return sorted(p.name for p in self.root.iterdir() if p.is_dir())
 
 
-def enable_xla_disk_cache(path: str | Path) -> None:
-    """Turn on XLA's persistent compilation cache (the gVisor-tier cold path)."""
-    Path(path).mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def disable_xla_disk_cache() -> None:
-    jax.config.update("jax_compilation_cache_dir", None)
+def use_checkout_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; entry points call it first.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
+    is set here. Otherwise the cache lives at a fixed path inside the checkout
+    (``CHECKOUT_CACHE_DIR``, ignored by git): the directory is part of each
+    entry's key, so a path built per run would never hit. Returns the
+    directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    CHECKOUT_CACHE_DIR.mkdir(exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)
+
+
+_DISK_CACHE_FLAGS = ("jax_enable_compilation_cache",
+                     "jax_persistent_cache_min_compile_time_secs",
+                     "jax_persistent_cache_min_entry_size_bytes")
+
+
+def _disk_cache_flags() -> tuple:
+    return tuple(getattr(jax.config, name) for name in _DISK_CACHE_FLAGS)
+
+
+def _set_disk_cache_flags(values: tuple) -> tuple:
+    """Set ``_DISK_CACHE_FLAGS`` and return what they were.
+
+    JAX decides on the first compile whether the persistent cache is in use
+    and keeps that answer until ``reset_cache()``, so a flag changed without
+    the reset would not reach the next compile.
+    """
+    previous = _disk_cache_flags()
+    for name, value in zip(_DISK_CACHE_FLAGS, values):
+        jax.config.update(name, value)
+    _jax_cc.reset_cache()
+    return previous
+
+
+def enable_xla_disk_cache() -> tuple:
+    """Cache every compile on disk (the gVisor-tier cold path), in the
+    directory ``use_checkout_compile_cache`` places. Returns the settings it
+    replaced, for ``disable_xla_disk_cache``."""
+    use_checkout_compile_cache()
+    return _set_disk_cache_flags((True, 0.0, 0))
+
+
+def disable_xla_disk_cache(previous: tuple) -> None:
+    """Restore the settings ``enable_xla_disk_cache`` replaced."""
+    _set_disk_cache_flags(previous)
+
+
+_off_lock = threading.Lock()
+_off_depth = 0
+_off_saved: tuple = ()
+
+
+@contextlib.contextmanager
+def persistent_cache_off():
+    """Compile inside with JAX's persistent cache neither read nor written —
+    the ``cold_jit`` tier's full recompile.
+
+    The switch is process-wide: a compile on another thread inside the window
+    misses the cache too (slower, never wrong). Windows may overlap; the last
+    one out restores the settings the first one found.
+    """
+    global _off_depth, _off_saved
+    with _off_lock:
+        if _off_depth == 0:
+            _off_saved = _set_disk_cache_flags((False,) + _disk_cache_flags()[1:])
+        _off_depth += 1
+    try:
+        yield
+    finally:
+        with _off_lock:
+            _off_depth -= 1
+            if _off_depth == 0:
+                _set_disk_cache_flags(_off_saved)
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def refuse_degrade_on_tpu(what: str, err: BaseException) -> None:
+    """On a TPU, raise (from ``err``) instead of degrading to an in-process
+    or fused program; elsewhere return and let the caller degrade.
+
+    The degrades exist for XLA:CPU's AOT loader, which can refuse executables
+    compiled for other machine features. On a TPU a refused or unverified
+    artifact is a fault, and serving around it would hide the device path.
+    """
+    if on_tpu():
+        raise RuntimeError(f"{what} failed on the TPU") from err

@@ -14,10 +14,11 @@
   4. record the ImageManifest.
 
 Invariants: every serialized image is verified by loading and running it once
-at deploy time — a host whose AOT loader rejects the blob degrades to the
+at deploy time — a CPU host whose AOT loader rejects the blob degrades to the
 in-process program (flagged ``aot_verified: false``) instead of crashing
-executors; compiles happen at deploy time only (bucket shapes included via
-``ensure_bucket``, once per bucket, ever) — no request ever pays a compile;
+executors, while on a TPU every such degrade raises; compiles happen at
+deploy time only (bucket shapes included via ``ensure_bucket``, once per
+bucket, ever) — no request ever pays a compile;
 ``program_key``/``bucket_image_key`` are the single source of truth shared
 with the scheduler's affinity probes and tier inserts.
 """
@@ -34,7 +35,8 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.artifact import ExecutorImage, FunctionSpec, ImageManifest
 from repro.core.compile_cache import (
-    CompileCache, decode_admit_key, decode_step_key, head_key, tail_key,
+    CompileCache, decode_admit_key, decode_step_key, head_key, refuse_degrade_on_tpu,
+    tail_key,
 )
 from repro.core.metrics import now
 from repro.core.snapshot import SnapshotStore, save_generic_checkpoint
@@ -106,11 +108,11 @@ def make_admit_fn(model: Model, max_pages: int, page_size: int) -> Callable:
     """Continuous-batching admit: prefill ONE request into its reserved pages.
 
     Prefills at the pool-table capacity (``max_pages * page_size``) so the
-    [L, capacity, ...] cache reshapes exactly into ``max_pages`` page-sized
-    rows, then scatters those rows to the chain's device pages via
-    ``page_ids`` ([max_pages] s32, padded with the null page — rows past the
-    chain's reservation land on page 0, which is garbage territory by
-    invariant). Returns the prompt's next-token logits ([V] — this is the
+    head-major [L, nkv, capacity, hd] cache reshapes exactly into
+    ``max_pages`` page-sized rows, then scatters those rows to the chain's
+    device pages via ``page_ids`` ([max_pages] s32, padded with the null
+    page — rows past the chain's reservation land on page 0, which is
+    garbage territory by invariant). Returns the prompt's next-token logits ([V] — this is the
     request's FIRST response token, the TTFR stamp) plus the updated pools.
     """
     capacity = max_pages * page_size
@@ -121,8 +123,10 @@ def make_admit_fn(model: Model, max_pages: int, page_size: int) -> Callable:
         inner = cache["inner"]
 
         def scatter(pool, new):
-            rows = new[:, 0].reshape(pool.shape[0], max_pages, page_size,
-                                     *pool.shape[3:])
+            # [L, nkv, capacity, hd] -> [L, max_pages, nkv, page_size, hd]
+            L, nkv, _, hd = new[:, 0].shape
+            rows = new[:, 0].reshape(L, nkv, max_pages, page_size, hd)
+            rows = jnp.swapaxes(rows, 1, 2)
             return pool.at[:, page_ids].set(rows.astype(pool.dtype))
 
         return logits[0], scatter(k_pages, inner["k"]), scatter(v_pages,
@@ -285,7 +289,8 @@ class Deployment:
                     self.cache.put_compiled(bkey, bucketed)
                     self.cache.load_program(bkey)      # verify it deserializes
                     fallback = None
-                except Exception:
+                except Exception as e:
+                    refuse_degrade_on_tpu(f"AOT load of bucket {rows}", e)
                     fallback = bucketed
             self._buckets[rows] = fallback
 
@@ -338,7 +343,8 @@ class Deployment:
                     step_p = self.cache.load_program(
                         decode_step_key(self.image.key))
                     verified = True
-                except Exception:
+                except Exception as e:
+                    refuse_degrade_on_tpu("AOT load of the decode bundle", e)
                     admit_p, step_p = admit_c, step_c
             self._decode_bundle = DecodeBundle(
                 slots=slots, page_size=page_size, n_pages=n_pages,
@@ -428,7 +434,8 @@ def deploy(spec: FunctionSpec, cache: CompileCache, snapshots: SnapshotStore,
     try:
         probe = cache.load_program(key)
         fused_out = jax.block_until_ready(probe(params, probe_tokens))
-    except Exception:
+    except Exception as e:
+        refuse_degrade_on_tpu("AOT load of the serve program", e)
         fallback_program = compiled
         fused_out = jax.block_until_ready(compiled(params, probe_tokens))
     fused_out = np.asarray(fused_out)
@@ -455,7 +462,10 @@ def deploy(spec: FunctionSpec, cache: CompileCache, snapshots: SnapshotStore,
             tok0 = np.asarray(jax.block_until_ready(tok0))
             split_ok = bool(np.array_equal(split_out, fused_out)
                             and np.array_equal(tok0[:, 0], fused_out[:, 0]))
-        except Exception:
+            if not split_ok:
+                raise ValueError("head+tail tokens differ from the fused program")
+        except Exception as e:
+            refuse_degrade_on_tpu("the head/tail split", e)
             split_ok = False
     if not split_ok:
         cache.evict(head_key(key))

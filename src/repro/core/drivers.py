@@ -279,17 +279,19 @@ class WarmDriver(Driver):
 
 
 class ColdJITDriver(Driver):
-    """Full Docker-stack analogue: re-trace + full XLA compile + generic checkpoint
-    (the trace/compile still overlaps the checkpoint parse — even the slow path
-    benefits from the staged pipeline)."""
+    """Full Docker-stack analogue: re-trace + full XLA compile, the persistent
+    cache kept out of it, + generic checkpoint (the trace/compile still
+    overlaps the checkpoint parse — even the slow path benefits from the
+    staged pipeline)."""
 
     name = "cold_jit"
     supports_preboot = True
     supports_batch = True          # TraceCompile re-traces at the bucket shape
+    disk_cache = False
 
     def plan(self, dep: Deployment) -> BootPlan:
         return BootPlan([
-            TraceCompile(),                                  # program track
+            TraceCompile(disk_cache=self.disk_cache),        # program track
             RestoreWeightsHost("generic"), DevicePut(),      # weights track
             Finalize(),
         ])
@@ -300,6 +302,7 @@ class ColdJITCachedDriver(ColdJITDriver):
     compile (enable via repro.core.compile_cache.enable_xla_disk_cache)."""
 
     name = "cold_jit_cached"
+    disk_cache = True
 
 
 ALL_DRIVERS = ("process", "fork", "unikernel", "unikernel_stream", "paused",

@@ -50,8 +50,6 @@ import jax
 import ml_dtypes
 import numpy as np
 
-from repro.dist import compat  # noqa: F401  (jax.tree.flatten_with_path shim)
-
 
 def _flatten_with_paths(tree) -> Tuple[List[Tuple[str, Any]], Any]:
     flat, treedef = jax.tree.flatten_with_path(tree)

@@ -10,8 +10,4 @@ Three modules:
                      all-reduce used for cross-pod (DCI) gradient traffic.
 * ``flash_decode`` — distributed flash decoding: LSE-merge over a
                      sequence-sharded KV cache (the ``serve_seqkv`` preset).
-
-Importing this package installs the jax API compatibility shims (``compat``)
-so the same source runs on the pinned jax as well as newer releases.
 """
-from repro.dist import compat  # noqa: F401  (side effect: jax API shims)

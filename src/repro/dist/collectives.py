@@ -16,8 +16,6 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.dist import compat  # noqa: F401  (jax.shard_map shim for callers)
-
 
 # ------------------------------------------------------------------ int8 codec
 

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
 from repro.dist.sharding import Rules
 
 
@@ -43,7 +42,7 @@ def decode_attention_seqsharded(q, k_cache, v_cache, length, mesh=None,
                                 block_kv: int = 1024):
     """Decode attention over a cache whose seq dim is sharded along ``axis``.
 
-    q: [B, Hq, D]; k_cache, v_cache: [B, S, Hkv, D] (S divisible by the axis
+    q: [B, Hq, D]; k_cache, v_cache: [B, Hkv, S, D] (S divisible by the axis
     size); length: int32 [] or [B].  Returns [B, Hq, D], numerically matching
     ``kernels.ref.decode_attention`` on the unsharded cache.
     """
@@ -52,13 +51,13 @@ def decode_attention_seqsharded(q, k_cache, v_cache, length, mesh=None,
     if mesh is None or axis is None:
         raise ValueError("decode_attention_seqsharded needs a mesh and an axis")
     n_shards = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
-    B, S = k_cache.shape[0], k_cache.shape[1]
+    B, S = k_cache.shape[0], k_cache.shape[2]
     if S % n_shards != 0:
         raise ValueError(f"cache seq {S} not divisible by {axis}={n_shards}")
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
 
     def shard_body(qb, kb, vb, lb):
-        s_local = kb.shape[1]
+        s_local = kb.shape[2]
         offset = jax.lax.axis_index(axis) * s_local
         local_len = jnp.clip(lb - offset, 0, s_local)
         m, l, acc = ref.decode_attention(qb, kb, vb, local_len,
@@ -71,9 +70,9 @@ def decode_attention_seqsharded(q, k_cache, v_cache, length, mesh=None,
         out = acc_g / l_safe[..., None]          # [B, Hkv, G, D]
         return out.reshape(qb.shape).astype(qb.dtype)
 
-    fn = compat.shard_map(
-        shard_body, mesh,
-        in_specs=(P(None, None, None), P(None, axis, None, None),
-                  P(None, axis, None, None), P(None)),
-        out_specs=P(None, None, None))
+    fn = jax.shard_map(
+        shard_body, mesh=mesh,
+        in_specs=(P(None, None, None), P(None, None, axis, None),
+                  P(None, None, axis, None), P(None)),
+        out_specs=P(None, None, None), check_vma=False)
     return fn(q, k_cache, v_cache, lengths)

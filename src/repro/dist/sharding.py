@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (jax API shims)
 
 # a mapping value: replicate / one mesh axis / a prefix-tuple of mesh axes
 Target = Union[None, str, Tuple[str, ...]]

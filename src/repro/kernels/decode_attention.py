@@ -7,8 +7,10 @@ sequential, so (m, l, acc) scratch carries across the KV sweep per (batch, kv-he
 all G = Hq/Hkv query heads of the group ride in one [G, D] block (MXU-friendly for
 GQA: the [G, D] x [D, block_kv] score matmul).
 
-Length masking comes in as an s32[B, 1] operand (positions >= length are dead —
-cache slots not yet written). A ragged cache depth (S % block_kv != 0) is
+The cache is head-major, [B, Hkv, S, D], so each K/V block is a [block_kv, D]
+tile in the trailing pair of dims, which is what Mosaic tiles. Lengths ride
+as a scalar-prefetch operand in SMEM (positions >= length are dead — cache
+slots not yet written). A ragged cache depth (S % block_kv != 0) is
 handled the same way, inside the kernel: the grid rounds up and the tail
 block's out-of-range positions fall under the mask. No host-side jnp.pad of
 the caches — that was a whole-cache copy per decoded token. The tail block's
@@ -34,6 +36,7 @@ DEFAULT_BLOCK_KV = 512
 
 def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                 block_kv: int, n_kv_blocks: int, s_max: int):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -43,10 +46,10 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0, :, :].astype(jnp.float32)                   # [G, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                   # [bk, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                         # [bk, D]
+    v = v_ref[0, 0].astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
-    length = jnp.minimum(len_ref[0, 0], s_max)
+    length = jnp.minimum(len_ref[b], s_max)
 
     kv_pos = ik * block_kv + jax.lax.broadcasted_iota(
         jnp.int32, (q.shape[0], block_kv), 1)                   # [G, bk]
@@ -81,10 +84,10 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def decode_attention(q, k_cache, v_cache, length, *,
                      block_kv: int = DEFAULT_BLOCK_KV, interpret: bool = False):
-    """q: [B, Hq, D]; k_cache, v_cache: [B, S, Hkv, D]; length: [] or [B] ->
+    """q: [B, Hq, D]; k_cache, v_cache: [B, Hkv, S, D]; length: [] or [B] ->
     [B, Hq, D]."""
     B, Hq, D = q.shape
-    _, S, Hkv, _ = k_cache.shape
+    _, Hkv, S, _ = k_cache.shape
     assert Hq % Hkv == 0
     G = Hq // Hkv
     block_kv = min(block_kv, max(8, 1 << (S - 1).bit_length()))
@@ -92,27 +95,30 @@ def decode_attention(q, k_cache, v_cache, length, *,
     # ceil grid: the tail block is masked inside the kernel — padding the
     # caches here would copy the whole KV cache once per decoded token
     nk = pl.cdiv(S, block_kv)
-    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,)).reshape(B, 1)
+    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
     qg = q.reshape(B, Hkv, G, D)
 
     kernel = functools.partial(_dec_kernel, block_kv=block_kv, n_kv_blocks=nk,
                                s_max=S)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                 # lengths
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),                 # lengths
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),     # q group
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, ik: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, ik: (b, ik, h, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik, ln: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik, ln: (b, h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik, ln: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
     )(lengths, qg, k_cache, v_cache)
     return out.reshape(B, Hq, D)

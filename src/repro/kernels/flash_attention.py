@@ -3,7 +3,11 @@
 Grid: (batch, q_heads, q_blocks, kv_blocks) — the kv axis is innermost, so on TPU it
 executes sequentially per (b, h, iq) and the online-softmax state (m, l, acc) lives
 in VMEM scratch across those steps (HBM->VMEM traffic is exactly one pass over K/V
-per q block — the flash property). The MXU sees [block_q, D] x [D, block_kv] and
+per q block — the flash property). The kernel runs on head-major [B, H, S, D]
+operands: Mosaic tiles the last two block dims, so a [block, D] tile must be
+the trailing pair. The public [B, S, H, D] signature stays; the wrapper
+transposes once per call (prefill is compute-bound, the copy is small next to
+the S^2 score work). The MXU sees [block_q, D] x [D, block_kv] and
 [block_q, block_kv] x [block_kv, D] matmuls; blocks default to 128x128 to match the
 128x128 systolic array, with fp32 accumulation.
 
@@ -42,9 +46,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)               # [bq, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)               # [bk, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                     # [bq, D]
+    k = k_ref[0, 0].astype(jnp.float32)                     # [bk, D]
+    v = v_ref[0, 0].astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
 
     q_pos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
@@ -73,7 +77,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
 def _pad_seq(x, block, axis):
@@ -97,11 +101,14 @@ def flash_attention_fwd_only(q, k, v, *, causal: bool = True, q_offset: int = 0,
     block_q = min(block_q, max(8, 1 << (Sq - 1).bit_length()))
     block_kv = min(block_kv, max(8, 1 << (Skv - 1).bit_length()))
 
-    qp = _pad_seq(q, block_q, 1)
-    kp = _pad_seq(k, block_kv, 1)
-    vp = _pad_seq(v, block_kv, 1)
-    nq = qp.shape[1] // block_q
-    nk = kp.shape[1] // block_kv
+    # head-major [B, H, S, D]: the [block, D] tile is the trailing pair.
+    # Transpose before padding, so the compiler shares the k/v transpose with
+    # a caller that keeps head-major k/v (prefill filling its KV cache).
+    qp = _pad_seq(jnp.swapaxes(q, 1, 2), block_q, 2)
+    kp = _pad_seq(jnp.swapaxes(k, 1, 2), block_kv, 2)
+    vp = _pad_seq(jnp.swapaxes(v, 1, 2), block_kv, 2)
+    nq = qp.shape[2] // block_q
+    nk = kp.shape[2] // block_kv
 
     kernel = functools.partial(
         _fa_kernel, causal=causal, q_offset=q_offset, skv=Skv,
@@ -111,11 +118,11 @@ def flash_attention_fwd_only(q, k, v, *, causal: bool = True, q_offset: int = 0,
         kernel,
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -124,7 +131,7 @@ def flash_attention_fwd_only(q, k, v, *, causal: bool = True, q_offset: int = 0,
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :Sq]
+    return jnp.swapaxes(out, 1, 2)[:, :Sq]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -152,6 +159,6 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
                     interpret: bool = False):
     """Differentiable entry point (Pallas fwd, recompute-reference bwd)."""
     if not isinstance(q_offset, int):
-        # traced offset (decode continuation) -> reference path handles it
-        return ref.flash_attention(q, k, v, causal=causal, q_offset=q_offset)
+        raise TypeError("flash_attention needs a static int q_offset, got "
+                        f"{type(q_offset).__name__}")
     return _flash(q, k, v, causal, q_offset, interpret)

@@ -99,12 +99,12 @@ def mlstm(q, k, v, i_raw, f_raw, state=None, *, chunk: int = DEFAULT_CHUNK,
           interpret: bool = False):
     """q, k: [B,S,H,Dk]; v: [B,S,H,Dv]; gates: [B,S,H] -> (h [B,S,H,Dv], (C,n,m)).
 
-    Fresh-state form (state=None). With a carried state (decode continuation) the
-    reference path is used — the kernel targets the long prefill/train sweep.
+    Fresh-state form only: the kernel targets the long prefill/train sweep, and
+    a carried state (decode continuation) is refused rather than run elsewhere.
     """
     if state is not None:
-        from repro.kernels import ref
-        return ref.mlstm_chunked(q, k, v, i_raw, f_raw, state=state)
+        raise NotImplementedError("the mlstm kernel starts from a fresh state; "
+                                  "continue a carried state with impl='ref'")
     B, S, H, Dk = q.shape
     Dv = v.shape[-1]
     chunk = min(chunk, max(8, 1 << (S - 1).bit_length()))

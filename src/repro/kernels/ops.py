@@ -9,6 +9,9 @@ Models call these entry points; the active implementation is selected by
 * ``pallas``    — compiled Pallas kernels (TPU execution target).
 * ``interpret`` — Pallas kernels in interpret mode (CPU correctness validation).
 * ``auto``      — ``pallas`` on TPU backends, ``ref`` elsewhere (default).
+
+On a TPU no entry point falls back to ``ref``: a kernel the chip's compiler
+refuses (``selective_scan`` and ``mlstm`` today) raises there.
 """
 from __future__ import annotations
 
@@ -70,7 +73,7 @@ def attention(q, k, v, *, causal: bool = True, q_offset=0):
 
 
 def decode_attention(q, k_cache, v_cache, length):
-    """Single-token attention vs cache. q: [B,Hq,D]; caches [B,S,Hkv,D]."""
+    """Single-token attention vs cache. q: [B,Hq,D]; caches [B,Hkv,S,D]."""
     impl = _resolved()
     if impl == "ref":
         return ref.decode_attention(q, k_cache, v_cache, length)
@@ -81,7 +84,7 @@ def decode_attention(q, k_cache, v_cache, length):
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths):
     """Single-token attention vs a paged KV cache. q: [B,Hq,D]; pages
-    [P,page_size,Hkv,D]; page_table [B,max_pages] s32; lengths [] or [B]."""
+    [P,Hkv,page_size,D]; page_table [B,max_pages] s32; lengths [] or [B]."""
     impl = _resolved()
     if impl == "ref":
         return ref.paged_decode_attention(q, k_pages, v_pages, page_table,

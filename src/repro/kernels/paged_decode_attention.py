@@ -7,14 +7,16 @@ without ever compacting or copying cache memory. This kernel consumes that
 layout directly:
 
     q:          [B, Hq, D]           one query token per sequence (GQA)
-    k_pages:    [P, page_size, Hkv, D]   the shared page pool
-    v_pages:    [P, page_size, Hkv, D]
+    k_pages:    [P, Hkv, page_size, D]   the shared page pool (head-major)
+    v_pages:    [P, Hkv, page_size, D]
     page_table: [B, max_pages] s32   page ids of each sequence's chain
     lengths:    [B] s32              live positions (0 = empty slot)
 
 Grid: (B, Hkv, max_pages) — the page axis innermost and sequential, so the
 online-softmax scratch (m, l, acc) carries across one sequence's page sweep
-exactly like the contiguous kernel. The page table and lengths ride as
+exactly like the contiguous kernel. Pages are head-major so each K/V block
+is a [page_size, D] tile in the trailing pair of dims, which is what Mosaic
+tiles. The page table and lengths ride as
 scalar-prefetch operands: each K/V block's HBM address is computed from
 ``table[b, ip]`` inside the BlockSpec index_map, so the gather costs no
 host-side copy and touches only the pages a sequence actually owns a table
@@ -49,8 +51,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0, :, :].astype(jnp.float32)                   # [G, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                   # [ps, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                         # [ps, D]
+    v = v_ref[0, 0].astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
     length = len_ref[b]
 
@@ -86,10 +88,10 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            interpret: bool = False):
-    """q: [B, Hq, D]; k_pages, v_pages: [P, page_size, Hkv, D];
+    """q: [B, Hq, D]; k_pages, v_pages: [P, Hkv, page_size, D];
     page_table: [B, max_pages] s32; lengths: [] or [B] s32 -> [B, Hq, D]."""
     B, Hq, D = q.shape
-    _, page_size, Hkv, _ = k_pages.shape
+    _, Hkv, page_size, _ = k_pages.shape
     assert Hq % Hkv == 0
     G = Hq // Hkv
     max_pages = page_table.shape[1]
@@ -104,10 +106,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         grid=(B, Hkv, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, ip, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, D),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, D),
+                         lambda b, h, ip, tbl, ln: (tbl[b, ip], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, D),
+                         lambda b, h, ip, tbl, ln: (tbl[b, ip], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, h, ip, tbl, ln: (b, h, 0, 0)),

@@ -125,13 +125,14 @@ def decode_attention(q, k_cache, v_cache, length, *, block_kv: int = 1024,
                      return_stats: bool = False):
     """Single-token attention against a KV cache (flash-decoding math).
 
-    q: [B, Hq, D]; k_cache, v_cache: [B, S, Hkv, D]; length: int32 [] or [B] —
+    q: [B, Hq, D]; k_cache, v_cache: [B, Hkv, S, D] (head-major, the cache
+    layout the models keep); length: int32 [] or [B] —
     positions >= length are masked out. Returns [B, Hq, D], or the raw online-
     softmax stats (m, l, acc) shaped [B,Hkv,G(,D)] for cross-shard LSE merging
     (distributed flash decoding).
     """
     B, Hq, D = q.shape
-    _, S, Hkv, _ = k_cache.shape
+    _, Hkv, S, _ = k_cache.shape
     G = Hq // Hkv
     block_kv = probe_block(min(block_kv, max(S, 16)), S)
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
@@ -146,16 +147,16 @@ def decode_attention(q, k_cache, v_cache, length, *, block_kv: int = 1024,
     qr = q.reshape(B, Hkv, G, D)
     if S % block_kv != 0:   # pad only when truly ragged (rare: S is a power of 2)
         pad = (-S) % block_kv
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
     def kv_block(carry, ki):
         m, l, acc = carry
         start = ki * block_kv
-        kb = jax.lax.dynamic_slice_in_dim(k_cache, start, block_kv, axis=1)
-        vb = jax.lax.dynamic_slice_in_dim(v_cache, start, block_kv, axis=1)
+        kb = jax.lax.dynamic_slice_in_dim(k_cache, start, block_kv, axis=2)
+        vb = jax.lax.dynamic_slice_in_dim(v_cache, start, block_kv, axis=2)
         kv_pos = start + jnp.arange(block_kv)
-        s = jnp.einsum("bhgd,bkhd->bhgk", qr, kb,
+        s = jnp.einsum("bhgd,bhkd->bhgk", qr, kb,
                        preferred_element_type=jnp.float32) * scale
         valid = ((kv_pos[None, :] < jnp.minimum(lengths, S)[:, None])
                  & (kv_pos[None, :] < S))                              # [B,bk]
@@ -165,7 +166,7 @@ def decode_attention(q, k_cache, v_cache, length, *, block_kv: int = 1024,
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1)
         acc_new = acc * corr[..., None] + jnp.einsum(
-            "bhgk,bkhd->bhgd", p.astype(k_cache.dtype), vb,
+            "bhgk,bhkd->bhgd", p.astype(k_cache.dtype), vb,
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc_new), None
 
@@ -183,7 +184,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            block_kv: int = 1024):
     """Single-token attention against a paged KV cache (oracle by gather).
 
-    q: [B, Hq, D]; k_pages, v_pages: [P, page_size, Hkv, D]; page_table:
+    q: [B, Hq, D]; k_pages, v_pages: [P, Hkv, page_size, D]; page_table:
     [B, max_pages] s32 (page ids per sequence, unused entries point at the
     null page 0); lengths: [] or [B] s32. Gathers each sequence's page chain
     into a contiguous cache and applies the exact contiguous decode math —
@@ -191,11 +192,15 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     are masked there.
     """
     B = q.shape[0]
-    _, page_size, Hkv, D = k_pages.shape
+    _, Hkv, page_size, D = k_pages.shape
     max_pages = page_table.shape[1]
     table = jnp.asarray(page_table, jnp.int32)
-    k = k_pages[table].reshape(B, max_pages * page_size, Hkv, D)
-    v = v_pages[table].reshape(B, max_pages * page_size, Hkv, D)
+
+    def gather(pages):                  # [B, max_pages, Hkv, ps, D] -> [B, Hkv, S, D]
+        return jnp.swapaxes(pages[table], 1, 2).reshape(
+            B, Hkv, max_pages * page_size, D)
+
+    k, v = gather(k_pages), gather(v_pages)
     return decode_attention(q, k, v, lengths, block_kv=block_kv)
 
 

@@ -1,7 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
-# ^ MUST precede every other import: jax locks the device count on first init.
+# ^ MUST precede every other import: jax locks the platform and the device
+# count on first init. A CPU-only tool: neither this process nor the cells it
+# starts as children may take a TPU.
 """Multi-pod dry-run: lower + compile every (architecture x shape) cell on the
 production mesh and extract the roofline inputs.
 
@@ -33,7 +36,6 @@ import jax
 
 from repro.configs import SHAPES, get_config, list_archs
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.dist import compat
 from repro.dist.sharding import (
     Rules, abstract_state, make_rules, param_shardings, use_rules,
 )
@@ -221,7 +223,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             record["memory"]["argument_size_in_bytes"]
             + record["memory"]["temp_size_in_bytes"]
             - record["memory"]["alias_size_in_bytes"])
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         record["flops_per_device"] = float(ca.get("flops", 0.0))
         record["bytes_accessed_per_device"] = float(ca.get("bytes accessed", 0.0))
         hlo = compiled.as_text()
@@ -246,7 +248,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                          donate_argnums=pdonate)
             with mesh:
                 pc = pj.lower(*pargs).compile()
-            pca = compat.cost_analysis(pc)
+            pca = pc.cost_analysis() or {}
             return (float(pca.get("flops", 0.0)),
                     float(pca.get("bytes accessed", 0.0)),
                     parse_collective_bytes(pc.as_text()))

@@ -3,8 +3,6 @@ module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-from repro.dist import compat  # noqa: F401  (back-fills AxisType/axis_types)
 from jax.sharding import AxisType
 
 
@@ -13,7 +11,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 
     The 'pod' axis is an outer pure-DP axis (cross-pod DCI); 'data'/'model' live on
     in-pod ICI. Requires xla_force_host_platform_device_count=512 on CPU (see
-    dryrun.py lines 1-2).
+    the top of dryrun.py).
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")

@@ -14,9 +14,11 @@ os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")  # silence AOT loader notices
 
 from repro.configs import list_archs  # noqa: E402
 from repro.core import FunctionSpec, Gateway  # noqa: E402
+from repro.core.compile_cache import use_checkout_compile_cache  # noqa: E402
 
 
 def main() -> None:
+    use_checkout_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="llama3.2-3b")
     ap.add_argument("--mode", choices=("cold", "warm"), default="cold")

@@ -1,4 +1,9 @@
-"""GQA attention in three modes: full (train), prefill (returns KV), cached decode."""
+"""GQA attention in three modes: full (train), prefill (returns KV), cached decode.
+
+KV caches are head-major — [B, nkv, S, hd] contiguous, [P, nkv, page_size,
+hd] paged — so the decode kernels read each head's [S, hd] slab as tiles in
+the trailing pair of dims, and no decode step transposes the cache.
+"""
 from __future__ import annotations
 
 from typing import Optional, Tuple
@@ -60,7 +65,8 @@ def attention_full(cfg, p: dict, x: jax.Array, positions: Optional[jax.Array], *
                    q_offset=0):
     """Full-sequence attention. kv_from: encoder output for cross-attention.
 
-    Returns (y [B,S,d], (k, v)) — k/v handed back so prefill can fill the cache.
+    Returns (y [B,S,d], (k, v)) — k/v handed back head-major ([B,nkv,S,hd])
+    so prefill can fill the cache.
     """
     q = _proj_q(cfg, p, x)
     src = x if kv_from is None else kv_from
@@ -72,14 +78,14 @@ def attention_full(cfg, p: dict, x: jax.Array, positions: Optional[jax.Array], *
     k = constrain(k, "batch", "kv_seq", "kv_heads", "head_dim")
     v = constrain(v, "batch", "kv_seq", "kv_heads", "head_dim")
     o = ops.attention(q, k, v, causal=causal and kv_from is None, q_offset=q_offset)
-    return _out(cfg, p, o), (k, v)
+    return _out(cfg, p, o), (jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
 
 
 def attention_decode(cfg, p: dict, x_t: jax.Array, k_cache: jax.Array,
                      v_cache: jax.Array, pos: jax.Array, *, cross: bool = False):
     """One-token attention against a cache.
 
-    x_t: [B,1,d]; k_cache/v_cache: [B,S,nkv,hd]; pos: int32 scalar (next
+    x_t: [B,1,d]; k_cache/v_cache: [B,nkv,S,hd]; pos: int32 scalar (next
     position, lock-step batch) or int32 [B] (per-row positions — the
     step-granular decode loop, where each slot sits at its own depth).
     Returns (y [B,1,d], k_cache', v_cache').
@@ -95,22 +101,23 @@ def attention_decode(cfg, p: dict, x_t: jax.Array, k_cache: jax.Array,
             k_t = positional(cfg, k_t, ppos)
         if jnp.ndim(pos) == 0:
             k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k_t.astype(k_cache.dtype), (0, pos, 0, 0))
+                k_cache, jnp.swapaxes(k_t, 1, 2).astype(k_cache.dtype), (0, 0, pos, 0))
             v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v_t.astype(v_cache.dtype), (0, pos, 0, 0))
+                v_cache, jnp.swapaxes(v_t, 1, 2).astype(v_cache.dtype), (0, 0, pos, 0))
         else:
             rows = jnp.arange(B)
-            k_cache = k_cache.at[rows, pos].set(k_t[:, 0].astype(k_cache.dtype))
-            v_cache = v_cache.at[rows, pos].set(v_t[:, 0].astype(v_cache.dtype))
+            # rows and pos are split by a slice: the indexed dims lead, [B,nkv,hd]
+            k_cache = k_cache.at[rows, :, pos].set(k_t[:, 0].astype(k_cache.dtype))
+            v_cache = v_cache.at[rows, :, pos].set(v_t[:, 0].astype(v_cache.dtype))
         length = pos + 1
     else:
-        length = k_cache.shape[1]
-    k_cache = constrain(k_cache, "batch", "kv_seq", "kv_heads", "head_dim")
-    v_cache = constrain(v_cache, "batch", "kv_seq", "kv_heads", "head_dim")
+        length = k_cache.shape[2]
+    k_cache = constrain(k_cache, "batch", "kv_heads", "kv_seq", "head_dim")
+    v_cache = constrain(v_cache, "batch", "kv_heads", "kv_seq", "head_dim")
     # distributed flash decoding when the cache sequence dim is mesh-sharded
     from repro.dist.flash_decode import decode_attention_seqsharded, seq_shard_axis
     rules, mesh = active_rules(), current_mesh()
-    axis = seq_shard_axis(rules, mesh, k_cache.shape[1])
+    axis = seq_shard_axis(rules, mesh, k_cache.shape[2])
     if axis is not None:
         o = decode_attention_seqsharded(q[:, 0], k_cache, v_cache, length,
                                         mesh, axis)
@@ -124,7 +131,7 @@ def attention_decode_paged(cfg, p: dict, x_t: jax.Array, k_pages: jax.Array,
                            pos: jax.Array):
     """One-token attention against a paged KV cache (continuous batching).
 
-    x_t: [B,1,d]; k_pages/v_pages: [P, page_size, nkv, hd] (the shared pool);
+    x_t: [B,1,d]; k_pages/v_pages: [P, nkv, page_size, hd] (the shared pool);
     page_table: [B, max_pages] s32; pos: [B] s32 per-row positions. Writes
     each row's new K/V into its chain's page at ``pos`` (empty slots carry an
     all-null page table, so their writes land on the reserved null page 0),
@@ -132,7 +139,7 @@ def attention_decode_paged(cfg, p: dict, x_t: jax.Array, k_pages: jax.Array,
     v_pages').
     """
     B = x_t.shape[0]
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     q = _proj_q(cfg, p, x_t)                                          # [B,1,nq,hd]
     if cfg.rope != "none":
         ppos = _decode_positions(cfg, B, pos)
@@ -143,8 +150,9 @@ def attention_decode_paged(cfg, p: dict, x_t: jax.Array, k_pages: jax.Array,
     rows = jnp.arange(B)
     page = page_table[rows, pos // page_size]                         # [B]
     off = pos % page_size
-    k_pages = k_pages.at[page, off].set(k_t[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v_t[:, 0].astype(v_pages.dtype))
+    # page and off are split by a slice: the indexed dims lead, [B,nkv,hd]
+    k_pages = k_pages.at[page, :, off].set(k_t[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[page, :, off].set(v_t[:, 0].astype(v_pages.dtype))
     o = ops.paged_decode_attention(q[:, 0], k_pages, v_pages, page_table,
                                    pos + 1)                           # [B,nq,hd]
     return _out(cfg, p, o[:, None]), k_pages, v_pages

@@ -126,7 +126,7 @@ class Model:
                      token: jax.Array):
         """One continuous-batching step against the shared page pool.
 
-        k_pages/v_pages: [L, P, page_size, nkv, hd]; page_table:
+        k_pages/v_pages: [L, P, nkv, page_size, hd]; page_table:
         [B, max_pages] s32; pos: [B] s32 (per-row current length — the host
         step loop owns it, mirroring the PagePool's chain state); token:
         [B, 1] s32. Returns (logits [B, V], k_pages', v_pages'). Rows whose

@@ -198,7 +198,7 @@ def _attn_block_decode_paged(cfg, p, x_t, k_pg, v_pg, page_table, pos, cf):
 def uniform_decode_paged(cfg, sp, x_t, k_pages, v_pages, page_table, pos):
     """Paged decode step for the uniform stack (continuous batching).
 
-    k_pages/v_pages: [Ls, P, page_size, nkv, hd] — one page pool per scanned
+    k_pages/v_pages: [Ls, P, nkv, page_size, hd] — one head-major page pool per scanned
     layer, sharing ONE page table (a logical page spans every layer, so the
     allocator accounts it once). pos: [B] s32 per-row. Unstacked head layers
     (Kimi first-k-dense) keep per-request caches and are not supported here.
@@ -218,7 +218,8 @@ def uniform_decode_paged(cfg, sp, x_t, k_pages, v_pages, page_table, pos):
 
 def uniform_page_pool_specs(cfg, n_pages: int, page_size: int):
     """Zero-init page-pool specs for the uniform stack: K and V pools shaped
-    [Ls, n_pages, page_size, nkv, hd] (page 0 is the reserved null page)."""
+    [Ls, n_pages, nkv, page_size, hd], head-major within a page (page 0 is
+    the reserved null page)."""
     m = cfg.moe
     first_k = m.first_k_dense if m else 0
     if first_k:
@@ -226,10 +227,10 @@ def uniform_page_pool_specs(cfg, n_pages: int, page_size: int):
     Ls = cfg.n_layers
     hd, nkv = cfg.resolved_head_dim, cfg.n_kv_heads
     dt = jnp.dtype(cfg.dtype)
-    axes = ("layers", None, "kv_seq", "kv_heads", "head_dim")
+    axes = ("layers", None, "kv_heads", "kv_seq", "head_dim")
     return {
-        "k_pages": _zeros_spec((Ls, n_pages, page_size, nkv, hd), dt, axes),
-        "v_pages": _zeros_spec((Ls, n_pages, page_size, nkv, hd), dt, axes),
+        "k_pages": _zeros_spec((Ls, n_pages, nkv, page_size, hd), dt, axes),
+        "v_pages": _zeros_spec((Ls, n_pages, nkv, page_size, hd), dt, axes),
     }
 
 
@@ -239,16 +240,16 @@ def uniform_cache_specs(cfg, batch: int, capacity: int):
     Ls = cfg.n_layers - first_k
     hd, nkv = cfg.resolved_head_dim, cfg.n_kv_heads
     dt = jnp.dtype(cfg.dtype)
-    kv_axes = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+    kv_axes = ("layers", "batch", "kv_heads", "kv_seq", "head_dim")
     specs = {
-        "k": _zeros_spec((Ls, batch, capacity, nkv, hd), dt, kv_axes),
-        "v": _zeros_spec((Ls, batch, capacity, nkv, hd), dt, kv_axes),
+        "k": _zeros_spec((Ls, batch, nkv, capacity, hd), dt, kv_axes),
+        "v": _zeros_spec((Ls, batch, nkv, capacity, hd), dt, kv_axes),
     }
     if first_k:
         specs["head"] = [
             {
-                "k": _zeros_spec((batch, capacity, nkv, hd), dt, kv_axes[1:]),
-                "v": _zeros_spec((batch, capacity, nkv, hd), dt, kv_axes[1:]),
+                "k": _zeros_spec((batch, nkv, capacity, hd), dt, kv_axes[1:]),
+                "v": _zeros_spec((batch, nkv, capacity, hd), dt, kv_axes[1:]),
             }
             for _ in range(first_k)
         ]
@@ -376,10 +377,10 @@ def jamba_cache_specs(cfg, batch: int, capacity: int):
                             ("layers", "layers", "batch", None, "ffn")),
         "ssm": _zeros_spec((P, n_mix, batch, d_in, ds), jnp.float32,
                            ("layers", "layers", "batch", "ffn", None)),
-        "k": _zeros_spec((P, batch, capacity, nkv, hd), dt,
-                         ("layers", "batch", "kv_seq", "kv_heads", None)),
-        "v": _zeros_spec((P, batch, capacity, nkv, hd), dt,
-                         ("layers", "batch", "kv_seq", "kv_heads", None)),
+        "k": _zeros_spec((P, batch, nkv, capacity, hd), dt,
+                         ("layers", "batch", "kv_heads", "kv_seq", None)),
+        "v": _zeros_spec((P, batch, nkv, capacity, hd), dt,
+                         ("layers", "batch", "kv_heads", "kv_seq", None)),
     }
 
 
@@ -553,12 +554,12 @@ def encdec_cache_specs(cfg, batch: int, capacity: int):
     Ld = cfg.n_layers
     hd, nkv = cfg.resolved_head_dim, cfg.n_kv_heads
     dt = jnp.dtype(cfg.dtype)
-    kv_axes = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+    kv_axes = ("layers", "batch", "kv_heads", "kv_seq", "head_dim")
     return {
-        "k": _zeros_spec((Ld, batch, capacity, nkv, hd), dt, kv_axes),
-        "v": _zeros_spec((Ld, batch, capacity, nkv, hd), dt, kv_axes),
-        "xk": _zeros_spec((Ld, batch, cfg.encoder_seq, nkv, hd), dt, kv_axes),
-        "xv": _zeros_spec((Ld, batch, cfg.encoder_seq, nkv, hd), dt, kv_axes),
+        "k": _zeros_spec((Ld, batch, nkv, capacity, hd), dt, kv_axes),
+        "v": _zeros_spec((Ld, batch, nkv, capacity, hd), dt, kv_axes),
+        "xk": _zeros_spec((Ld, batch, nkv, cfg.encoder_seq, hd), dt, kv_axes),
+        "xv": _zeros_spec((Ld, batch, nkv, cfg.encoder_seq, hd), dt, kv_axes),
     }
 
 

@@ -148,3 +148,98 @@ def test_cache_key_distinguishes_specs():
     b = FunctionSpec("llama3.2-3b", 2, 32, 2)
     c = FunctionSpec("olmo-1b", 2, 16, 2)
     assert len({a.cache_key(), b.cache_key(), c.cache_key()}) == 3
+
+
+@pytest.fixture
+def jax_cache_config():
+    """Restore JAX's persistent-cache settings after a test moves them."""
+    import jax
+    names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    from jax.experimental.compilation_cache import compilation_cache
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield jax.config
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()     # JAX memoizes cache use until reset
+
+
+def test_compile_cache_env_dir_is_left_to_jax(monkeypatch, jax_cache_config):
+    from repro.core import compile_cache
+    jax_cache_config.update("jax_compilation_cache_dir", "/set/by/jax/from/env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/by/jax/from/env")
+    monkeypatch.setattr(compile_cache, "CHECKOUT_CACHE_DIR", None)  # never touched
+    assert compile_cache.use_checkout_compile_cache() == "/set/by/jax/from/env"
+    previous = compile_cache.enable_xla_disk_cache()
+    assert jax_cache_config.jax_compilation_cache_dir == "/set/by/jax/from/env"
+    compile_cache.disable_xla_disk_cache(previous)
+
+
+def test_compile_cache_defaults_to_a_fixed_checkout_dir(tmp_path, monkeypatch,
+                                                        jax_cache_config):
+    from repro.core import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "CHECKOUT_CACHE_DIR", tmp_path / ".jax_cache")
+    first = compile_cache.use_checkout_compile_cache()
+    assert first == compile_cache.use_checkout_compile_cache() == \
+        str(tmp_path / ".jax_cache") == jax_cache_config.jax_compilation_cache_dir
+    assert (tmp_path / ".jax_cache").is_dir()
+
+
+def test_disk_cache_toggle_restores_previous_settings(tmp_path, monkeypatch,
+                                                      jax_cache_config):
+    from repro.core import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "CHECKOUT_CACHE_DIR", tmp_path / ".jax_cache")
+    jax_cache_config.update("jax_persistent_cache_min_compile_time_secs", 2.5)
+    previous = compile_cache.enable_xla_disk_cache()
+    assert jax_cache_config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax_cache_config.jax_enable_compilation_cache is True
+    compile_cache.disable_xla_disk_cache(previous)
+    assert jax_cache_config.jax_persistent_cache_min_compile_time_secs == 2.5
+    assert jax_cache_config.jax_compilation_cache_dir == str(tmp_path / ".jax_cache")
+
+
+def test_persistent_cache_off_restores_on_the_last_exit(jax_cache_config):
+    from repro.core import compile_cache
+    jax_cache_config.update("jax_enable_compilation_cache", True)
+    with compile_cache.persistent_cache_off():
+        with compile_cache.persistent_cache_off():
+            assert jax_cache_config.jax_enable_compilation_cache is False
+        assert jax_cache_config.jax_enable_compilation_cache is False
+    assert jax_cache_config.jax_enable_compilation_cache is True
+
+
+def test_cold_jit_never_reads_the_persistent_cache(gateway, tmp_path, monkeypatch,
+                                                   jax_cache_config):
+    """``cold_jit`` recompiles in full even after ``cold_jit_cached`` wrote
+    the same program to the persistent cache the process is using."""
+    import jax.monitoring
+
+    from repro.core import compile_cache
+    gw, spec = gateway
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "CHECKOUT_CACHE_DIR", tmp_path / ".jax_cache")
+    compile_cache.use_checkout_compile_cache()
+    hits = []
+
+    def on_event(event, **kwargs):
+        if event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+
+    jax.monitoring.register_event_listener(on_event)
+    previous = compile_cache.enable_xla_disk_cache()
+    try:
+        gw.invoke(spec.name, driver="cold_jit_cached", label="pcache:populate")
+        gw.invoke(spec.name, driver="cold_jit_cached", label="pcache:hit")
+        assert hits, "cold_jit_cached never hit, so a hit cannot be seen here"
+        hits.clear()
+        for _ in range(2):
+            gw.invoke(spec.name, driver="cold_jit", label="pcache:jit")
+        assert hits == []
+        gw.invoke(spec.name, driver="cold_jit_cached", label="pcache:again")
+        assert hits, "the cache stayed off after cold_jit's compile"
+    finally:
+        compile_cache.disable_xla_disk_cache(previous)
+        jax.monitoring.unregister_event_listener(on_event)

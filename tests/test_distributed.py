@@ -172,8 +172,8 @@ def test_distributed_flash_decode_matches_ref():
     B, S, Hq, Hkv, D = 2, 64, 8, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (B, Hq, D))
-    kc = jax.random.normal(ks[1], (B, S, Hkv, D))
-    vc = jax.random.normal(ks[2], (B, S, Hkv, D))
+    kc = jax.random.normal(ks[1], (B, Hkv, S, D))
+    vc = jax.random.normal(ks[2], (B, Hkv, S, D))
     length = jnp.array([37, 64], jnp.int32)
 
     with mesh:

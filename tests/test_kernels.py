@@ -84,8 +84,8 @@ def test_decode_attention_vs_ref(case, dtype):
     B, S, Hq, Hkv, D = case
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
-    kc = jax.random.normal(ks[1], (B, S, Hkv, D), dtype)
-    vc = jax.random.normal(ks[2], (B, S, Hkv, D), dtype)
+    kc = jax.random.normal(ks[1], (B, Hkv, S, D), dtype)     # head-major cache
+    vc = jax.random.normal(ks[2], (B, Hkv, S, D), dtype)
     length = jax.random.randint(ks[3], (B,), 1, S + 1)
     out = decode_attention(q, kc, vc, length, block_kv=64, interpret=True)
     exp = ref.decode_attention(q, kc, vc, length)
@@ -97,10 +97,11 @@ def test_decode_ref_vs_naive_oracle():
     ks = jax.random.split(KEY, 3)
     B, S, Hq, Hkv, D = 2, 50, 4, 2, 16
     q = jax.random.normal(ks[0], (B, Hq, D))
-    kc = jax.random.normal(ks[1], (B, S, Hkv, D))
-    vc = jax.random.normal(ks[2], (B, S, Hkv, D))
+    kc = jax.random.normal(ks[1], (B, Hkv, S, D))            # head-major cache
+    vc = jax.random.normal(ks[2], (B, Hkv, S, D))
     got = ref.decode_attention(q, kc, vc, jnp.int32(S), block_kv=16)
-    exp = ref.naive_attention(q[:, None], kc, vc, causal=False)[:, 0]
+    exp = ref.naive_attention(q[:, None], jnp.swapaxes(kc, 1, 2),
+                              jnp.swapaxes(vc, 1, 2), causal=False)[:, 0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=2e-5)
 
 

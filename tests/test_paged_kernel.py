@@ -30,7 +30,8 @@ def tol(dtype):
 
 def _build_paged(key, B, max_pages, page_size, Hq, Hkv, D, dtype, *,
                  lengths, null_fill=0.0, shuffle_seed=None, map_dead=True):
-    """Scatter a contiguous [B, S] cache into a shared page pool.
+    """Scatter a contiguous head-major [B, Hkv, S, D] cache into a shared
+    head-major page pool [P, Hkv, page_size, D].
 
     Returns (q, k_cache, v_cache, k_pages, v_pages, table, lengths_arr).
     ``map_dead=False`` leaves table entries past each row's live pages at the
@@ -39,24 +40,27 @@ def _build_paged(key, B, max_pages, page_size, Hq, Hkv, D, dtype, *,
     S = max_pages * page_size
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, Hq, D), dtype)
-    k_cache = jax.random.normal(kk, (B, S, Hkv, D), dtype)
-    v_cache = jax.random.normal(kv, (B, S, Hkv, D), dtype)
+    k_cache = jax.random.normal(kk, (B, Hkv, S, D), dtype)
+    v_cache = jax.random.normal(kv, (B, Hkv, S, D), dtype)
 
     P = 1 + B * max_pages
     ids = np.arange(1, P)
     if shuffle_seed is not None:
         ids = np.random.RandomState(shuffle_seed).permutation(ids)
-    k_pages = jnp.full((P, page_size, Hkv, D), null_fill, dtype)
-    v_pages = jnp.full((P, page_size, Hkv, D), null_fill, dtype)
+    k_pages = jnp.full((P, Hkv, page_size, D), null_fill, dtype)
+    v_pages = jnp.full((P, Hkv, page_size, D), null_fill, dtype)
+
+    def page_rows(cache, live):      # [Hkv, S, D] -> [live, Hkv, page_size, D]
+        rows = cache.reshape(Hkv, max_pages, page_size, D)
+        return jnp.swapaxes(rows, 0, 1)[:live]
+
     table = np.full((B, max_pages), NULL_PAGE, np.int32)
     for b in range(B):
         live = max_pages if map_dead else -(-int(lengths[b]) // page_size)
         pages = ids[b * max_pages:b * max_pages + live]
         table[b, :live] = pages
-        rows = k_cache[b].reshape(max_pages, page_size, Hkv, D)[:live]
-        k_pages = k_pages.at[pages].set(rows)
-        rows = v_cache[b].reshape(max_pages, page_size, Hkv, D)[:live]
-        v_pages = v_pages.at[pages].set(rows)
+        k_pages = k_pages.at[pages].set(page_rows(k_cache[b], live))
+        v_pages = v_pages.at[pages].set(page_rows(v_cache[b], live))
     return (q, k_cache, v_cache, k_pages, v_pages,
             jnp.asarray(table), jnp.asarray(lengths, jnp.int32))
 
@@ -168,8 +172,8 @@ def test_contiguous_kernel_ragged_tail_without_host_pad(S, monkeypatch):
     monkeypatch.setattr(da, "jnp", _NoPad())
     kq, kk, kv = jax.random.split(jax.random.fold_in(KEY, 5), 3)
     q = jax.random.normal(kq, (2, 4, 16), jnp.float32)
-    k_cache = jax.random.normal(kk, (2, S, 2, 16), jnp.float32)
-    v_cache = jax.random.normal(kv, (2, S, 2, 16), jnp.float32)
+    k_cache = jax.random.normal(kk, (2, 2, S, 16), jnp.float32)
+    v_cache = jax.random.normal(kv, (2, 2, S, 16), jnp.float32)
     lengths = jnp.asarray([S, max(1, S - 7)], jnp.int32)
     got = da.decode_attention(q, k_cache, v_cache, lengths,
                               block_kv=16, interpret=True)
