@@ -18,7 +18,9 @@ XLA executors:
 Every stage's duration lands in ``Timeline.stage_s[stage.name]`` and the
 combined wall time in ``Timeline.t_boot_wall``, so the benchmarks can report a
 per-stage startup breakdown exactly like the paper's container-layer tables —
-and show the overlap win directly (wall < sum of stages).
+and show the overlap win directly (wall < sum of stages). Each stage also runs
+inside a ``boot.<stage name>`` span (:func:`repro.core.metrics.span`) on the
+thread of its track, so a profiler trace shows the two tracks side by side.
 
 Streamed boots (``StreamRestore``/``FinalizeStream``, the ``unikernel_stream``
 driver) relax the all-at-once join: the weights track opens per-leaf
@@ -53,7 +55,7 @@ import numpy as np
 from repro.core.compile_cache import (CompileCache, persistent_cache_off,
                                       refuse_degrade_on_tpu)
 from repro.core.executor import Executor, ReadinessGates, SplitServe
-from repro.core.metrics import Timeline, now
+from repro.core.metrics import BOOT_SPAN_PREFIX, Timeline, now, span
 
 
 def spawn_future(fn: Callable[[], Any], name: str) -> Future:
@@ -943,7 +945,8 @@ class BootEngine:
                     if deadline is not None:
                         deadline.check(f"boot stage {stage.name}")
                     t0 = now()
-                    stage.run(ctx)
+                    with span(BOOT_SPAN_PREFIX + stage.name):
+                        stage.run(ctx)
                     dt = now() - t0
                     # sub-stage splits (e.g. restore_delta's chunk fetches)
                     # are carved OUT of the parent stage's time, so stage_s

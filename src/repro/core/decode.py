@@ -29,17 +29,24 @@ next admission decision, and admission is deterministic — if the pool cannot
 cover a request's worst case (prompt + max_new), the request WAITS at the
 queue head rather than corrupting a resident chain; the executor is never
 exited while a request is resident.
+
+Tracing: every phase of the loop runs inside a span of
+:data:`repro.core.metrics.SPANS` (submit, admit, boot, step and its inputs,
+run, pull and sample, idle, cool), so a profiler trace puts each moment the
+device idles down to what the loop was doing; a request's ``decode.submit``
+and ``decode.admit`` carry the same ``req``.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from repro.core.metrics import Recorder, Series, Timeline
+from repro.core.metrics import Recorder, Timeline, span, step_span
 from repro.core.metrics import now as _default_now
 from repro.core.paging import PageChain, PagePool
 
@@ -64,6 +71,7 @@ class _Request:
     timeline: Timeline
     label: Optional[str]
     deadline: Optional[Any]
+    rid: str                       # the spans' request id: the label, else a number
 
 
 @dataclasses.dataclass
@@ -121,8 +129,9 @@ class DecodeScheduler:
         self.admit_waits = 0           # admission deferred on page exhaustion
         self.boots = 0
         self.cooldowns = 0
-        self.queue_delay_s = Series()
-        self.tokens_per_request = Series()
+        self._queue_delay_sum_s = 0.0  # over admissions, failed ones included
+        self._queue_delay_n = 0
+        self._ids = itertools.count(1)
         self._thread = threading.Thread(target=self._loop,
                                         name=f"decode-{dep.name}", daemon=True)
         self._thread.start()
@@ -130,42 +139,44 @@ class DecodeScheduler:
     # ------------------------------------------------------------------ public
     def submit(self, tokens: np.ndarray, max_new: Optional[int] = None,
                label: Optional[str] = None, deadline=None) -> Future:
-        tokens = np.asarray(tokens, np.int32)
-        fut: Future = Future()
-        if tokens.shape != (1, self.dep.spec.prompt_len):
-            fut.set_exception(ValueError(
-                f"decode prompt must be [1, {self.dep.spec.prompt_len}], "
-                f"got {tokens.shape}"))
-            return fut
-        if max_new is None:
-            budget = self.default_max_new
-        else:
-            budget = int(max_new)
-            if not 1 <= budget <= self.default_max_new:
-                # admit always produces one token, so 0 cannot be honored;
-                # silently clamping an over-budget ask would truncate output
+        rid = label if label is not None else f"{self.dep.name}#{next(self._ids)}"
+        with span("decode.submit", req=rid):
+            tokens = np.asarray(tokens, np.int32)
+            fut: Future = Future()
+            if tokens.shape != (1, self.dep.spec.prompt_len):
                 fut.set_exception(ValueError(
-                    f"max_new must be in [1, {self.default_max_new}] "
-                    f"(the deployment's decode budget), got {budget}"))
+                    f"decode prompt must be [1, {self.dep.spec.prompt_len}], "
+                    f"got {tokens.shape}"))
                 return fut
-        worst = self.pool.pages_for(tokens.shape[1] + budget)
-        if worst > min(self.bundle.n_pages - 1, self.bundle.max_pages):
-            fut.set_exception(ValueError(
-                f"request needs {worst} pages; pool/table caps at "
-                f"{min(self.bundle.n_pages - 1, self.bundle.max_pages)}"))
+            if max_new is None:
+                budget = self.default_max_new
+            else:
+                budget = int(max_new)
+                if not 1 <= budget <= self.default_max_new:
+                    # admit always produces one token, so 0 cannot be honored;
+                    # silently clamping an over-budget ask would truncate output
+                    fut.set_exception(ValueError(
+                        f"max_new must be in [1, {self.default_max_new}] "
+                        f"(the deployment's decode budget), got {budget}"))
+                    return fut
+            worst = self.pool.pages_for(tokens.shape[1] + budget)
+            if worst > min(self.bundle.n_pages - 1, self.bundle.max_pages):
+                fut.set_exception(ValueError(
+                    f"request needs {worst} pages; pool/table caps at "
+                    f"{min(self.bundle.n_pages - 1, self.bundle.max_pages)}"))
+                return fut
+            tl = Timeline()
+            tl.t_enqueue = self._now()
+            tl.deadline = deadline
+            req = _Request(tokens, budget, fut, tl, label, deadline, rid)
+            with self._wake:
+                if not self._running:
+                    fut.set_exception(RuntimeError("decode scheduler closed"))
+                    return fut
+                self._queue.append(req)
+                self.requests += 1
+                self._wake.notify()
             return fut
-        tl = Timeline()
-        tl.t_enqueue = self._now()
-        tl.deadline = deadline
-        req = _Request(tokens, budget, fut, tl, label, deadline)
-        with self._wake:
-            if not self._running:
-                fut.set_exception(RuntimeError("decode scheduler closed"))
-                return fut
-            self._queue.append(req)
-            self.requests += 1
-            self._wake.notify()
-        return fut
 
     def drain(self, timeout_s: float = 600.0) -> None:
         """Block until every submitted request has settled."""
@@ -197,7 +208,8 @@ class DecodeScheduler:
             "admit_waits": float(self.admit_waits),
             "boots": float(self.boots),
             "cooldowns": float(self.cooldowns),
-            "queue_delay_mean_s": self.queue_delay_s.mean,
+            "queue_delay_mean_s": (self._queue_delay_sum_s / self._queue_delay_n
+                                   if self._queue_delay_n else float("nan")),
             "pages_high_water": float(self.pool.high_water),
             "page_alloc_failures": float(self.pool.alloc_failures),
         }
@@ -214,8 +226,9 @@ class DecodeScheduler:
                             self._now() - self._idle_since >= self.cfg.cool_after_s:
                         pass               # fall through to cool below
                     else:
-                        self._wake.wait(timeout=self.cfg.cool_after_s / 2
-                                        if self._ex is not None else 0.25)
+                        with span("decode.idle"):
+                            self._wake.wait(timeout=self.cfg.cool_after_s / 2
+                                            if self._ex is not None else 0.25)
                         continue
             if not busy:
                 self._cool()
@@ -261,23 +274,24 @@ class DecodeScheduler:
     def _ensure_booted(self, tl: Timeline) -> None:
         if self._ex is not None:
             return
-        host = self.cluster.route(self.dep.image.key)
-        driver = host.drivers[self.cfg.driver]
-        tl.t_start_begin = self._now()
-        ex = driver.start(self.dep, tl)
-        try:
-            gates = getattr(ex, "gates", None)
-            if gates is not None:
-                gates.bind_timeline(tl)
-            pools = self.dep.model.init_page_pool(self.bundle.n_pages,
-                                                  self.bundle.page_size)
-        except Exception:
-            # the started executor was never published to self._ex: exit it
-            # here (with residency accounting) or it leaks forever
-            ex.exit()
-            if self.on_exit is not None:
-                self.on_exit(ex)
-            raise
+        with span("decode.boot"):
+            host = self.cluster.route(self.dep.image.key)
+            driver = host.drivers[self.cfg.driver]
+            tl.t_start_begin = self._now()
+            ex = driver.start(self.dep, tl)
+            try:
+                gates = getattr(ex, "gates", None)
+                if gates is not None:
+                    gates.bind_timeline(tl)
+                pools = self.dep.model.init_page_pool(self.bundle.n_pages,
+                                                      self.bundle.page_size)
+            except Exception:
+                # the started executor was never published to self._ex: exit
+                # it here (with residency accounting) or it leaks forever
+                ex.exit()
+                if self.on_exit is not None:
+                    self.on_exit(ex)
+                raise
         self._k_pages, self._v_pages = pools["k_pages"], pools["v_pages"]
         self._ex, self._host = ex, host
         self.boots += 1
@@ -290,9 +304,10 @@ class DecodeScheduler:
         self._k_pages = self._v_pages = None
         if ex is None:
             return
-        ex.exit()
-        if self.on_exit is not None:
-            self.on_exit(ex)
+        with span("decode.cool"):
+            ex.exit()
+            if self.on_exit is not None:
+                self.on_exit(ex)
         self.cooldowns += 1
 
     # -------------------------------------------------------------- admission
@@ -333,63 +348,77 @@ class DecodeScheduler:
     def _admit(self, slot: int, req: _Request, chain: PageChain) -> None:
         tl = req.timeline
         tl.t_dispatch = self._now()
-        self.queue_delay_s.add(tl.t_dispatch - tl.t_enqueue)
-        try:
-            if req.deadline is not None:
-                req.deadline.check("decode-admit")
-            self._ensure_booted(tl)
-            if not tl.t_start_begin:
-                tl.t_start_begin = tl.t_dispatch
-            tl.t_exec_begin = self._now()
-            page_ids = chain.table_row(self.bundle.max_pages)
-            logits, self._k_pages, self._v_pages = self._ex.run_decode(
-                self.bundle.admit, req.tokens, self._k_pages, self._v_pages,
-                page_ids, timeline=tl)
-        except Exception as e:              # noqa: BLE001
-            self.pool.release(chain)
-            if not req.future.done():
-                req.future.set_exception(e)
-            return
-        tok0 = int(np.argmax(np.asarray(logits, np.float32)))
-        self.admits += 1
-        active = _Active(req=req, chain=chain, pos=req.tokens.shape[1],
-                         toks=[tok0])
-        if self._finished(active, tok0):
-            self._retire(active)            # EOS on the very first token
-        else:
-            self._slots[slot] = active
+        wait_s = tl.t_dispatch - tl.t_enqueue
+        self._queue_delay_sum_s += wait_s
+        self._queue_delay_n += 1
+        with span("decode.admit", req=req.rid, slot=slot,
+                  queue_wait_us=round(wait_s * 1e6)):
+            try:
+                if req.deadline is not None:
+                    req.deadline.check("decode-admit")
+                self._ensure_booted(tl)
+                if not tl.t_start_begin:
+                    tl.t_start_begin = tl.t_dispatch
+                tl.t_exec_begin = self._now()
+                page_ids = chain.table_row(self.bundle.max_pages)
+                with span("decode.admit.run"):
+                    logits, self._k_pages, self._v_pages = self._ex.run_decode(
+                        self.bundle.admit, req.tokens, self._k_pages,
+                        self._v_pages, page_ids, timeline=tl)
+            except Exception as e:          # noqa: BLE001
+                self.pool.release(chain)
+                if not req.future.done():
+                    req.future.set_exception(e)
+                return
+            with span("decode.admit.pull"):
+                tok0 = int(np.argmax(np.asarray(logits, np.float32)))
+            self.admits += 1
+            active = _Active(req=req, chain=chain, pos=req.tokens.shape[1],
+                             toks=[tok0])
+            if self._finished(active, tok0):
+                self._retire(active)        # EOS on the very first token
+            else:
+                self._slots[slot] = active
 
     # ------------------------------------------------------------------- step
     def _step_once(self) -> None:
         live = [(i, a) for i, a in enumerate(self._slots) if a is not None]
         if not live:
             return
-        mp = self.bundle.max_pages
-        table = np.zeros((self.slots, mp), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        tok = np.zeros((self.slots, 1), np.int32)
-        for i, a in live:
-            table[i] = a.chain.table_row(mp)
-            pos[i] = a.pos
-            tok[i, 0] = a.toks[-1]
-        logits, self._k_pages, self._v_pages = self._ex.run_decode(
-            self.bundle.step, self._k_pages, self._v_pages, table, pos, tok)
-        logits = np.asarray(logits, np.float32)
-        self.steps += 1
-        self.step_rows += len(live)
-        for i, a in live:
-            nxt = int(np.argmax(logits[i]))
-            a.pos += 1                      # the step wrote tok[i] at pos
-            a.toks.append(nxt)
-            expired = False
-            if a.req.deadline is not None:
-                try:
-                    a.req.deadline.check("decode-step")
-                except Exception:           # noqa: BLE001 — settle truncated
-                    expired = True
-            if expired or self._finished(a, nxt):
-                self._slots[i] = None       # freed BEFORE the next admission
-                self._retire(a)
+        # ctx_tokens: the keys the step attends over, each row's new one included
+        with step_span("decode.step", step_num=self.steps, rows=len(live),
+                       ctx_tokens=sum(a.pos + 1 for _, a in live)):
+            with span("decode.step.inputs"):
+                mp = self.bundle.max_pages
+                table = np.zeros((self.slots, mp), np.int32)
+                pos = np.zeros((self.slots,), np.int32)
+                tok = np.zeros((self.slots, 1), np.int32)
+                for i, a in live:
+                    table[i] = a.chain.table_row(mp)
+                    pos[i] = a.pos
+                    tok[i, 0] = a.toks[-1]
+            with span("decode.step.run"):
+                logits, self._k_pages, self._v_pages = self._ex.run_decode(
+                    self.bundle.step, self._k_pages, self._v_pages, table,
+                    pos, tok)
+            with span("decode.step.pull"):
+                logits = np.asarray(logits, np.float32)
+            self.steps += 1
+            self.step_rows += len(live)
+            with span("decode.step.sample"):
+                for i, a in live:
+                    nxt = int(np.argmax(logits[i]))
+                    a.pos += 1              # the step wrote tok[i] at pos
+                    a.toks.append(nxt)
+                    expired = False
+                    if a.req.deadline is not None:
+                        try:
+                            a.req.deadline.check("decode-step")
+                        except Exception:   # noqa: BLE001 — settle truncated
+                            expired = True
+                    if expired or self._finished(a, nxt):
+                        self._slots[i] = None   # freed BEFORE the next admission
+                        self._retire(a)
 
     def _finished(self, a: _Active, last_tok: int) -> bool:
         if len(a.toks) >= a.req.max_new:
@@ -400,7 +429,6 @@ class DecodeScheduler:
     def _retire(self, a: _Active) -> None:
         self.pool.release(a.chain)
         self.tokens_generated += len(a.toks)
-        self.tokens_per_request.add(len(a.toks))
         tl = a.req.timeline
         tl.t_done = self._now()
         self.recorder.add(a.req.label or f"{self.dep.name}:decode", tl)

@@ -5,6 +5,8 @@ medians (Table I). ``LatencyStats`` reproduces exactly those statistics; ``Timel
 records the per-request phase breakdown (queue wait / startup / execution), mirroring
 the cold-start decomposition in Sec III-C; ``ResidencyTracker`` integrates
 device-memory-seconds so the warm-pool "resource waste" claim is measurable.
+``span`` and ``step_span`` mark the program's own host work on the profiler's
+clock (names in ``SPANS``), and ``compile_stats`` counts the process's compiles.
 
 Invariants: every request gets exactly one Timeline per recorder label (batch
 members each get their own view sharing the batch's boot/exec stamps but
@@ -14,11 +16,14 @@ only ever accumulate (one delta restore per boot, summed across retries).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import functools
 import threading
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.simclock import REAL, Clock
@@ -378,3 +383,81 @@ def use_clock(clock: "Clock"):
 
 def now() -> float:
     return _clock.now()
+
+
+# ------------------------------------------------------------------ tracing
+# The program's own spans. Each is a ``jax.profiler.TraceAnnotation``: it costs
+# about a microsecond when no profiler trace is being recorded, and otherwise
+# lands in the same trace as the device's operations, on the same clock, on the
+# line of the thread that ran it.
+span = jax.profiler.TraceAnnotation
+# A step the profiler groups by: the event jax.profiler.StepTraceAnnotation
+# records (its ``_r=1`` root marker, and ``step_num``), made without that
+# class's Python-level __init__, which about doubles a span's cost when no
+# trace is being recorded.
+step_span = functools.partial(jax.profiler.TraceAnnotation, _r=1)
+
+SPANS = (
+    "decode.submit",       # DecodeScheduler.submit, on the caller's thread; stat req
+    "decode.admit",        # one admission; stats req, slot, queue_wait_us
+    "decode.admit.run",    # the admit program: dispatch and block_until_ready
+    "decode.admit.pull",   # the first token's logits to the host, and its argmax
+    "decode.boot",         # executor boot and page pools, in the admit that needs them
+    "decode.step",         # one step (step_span); stats step_num, rows, ctx_tokens
+    "decode.step.inputs",  # the page table, positions and tokens built on the host
+    "decode.step.run",     # the step program: dispatch and block_until_ready
+    "decode.step.pull",    # the [slots, vocab] logits to the host
+    "decode.step.sample",  # argmax, deadline checks and retiring finished rows
+    "decode.idle",         # the decode loop waiting for work
+    "decode.cool",         # the executor's exit when the decode tier cools to zero
+)
+BOOT_SPAN_PREFIX = "boot."  # boot.<stage name>: one boot stage, on its track's thread
+
+# jax.monitoring's compile events: the first fires on every jit cache miss
+# (whether or not the persistent cache then serves the executable)
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+COMPILE_LOG = 4096       # compile_times() keeps the times of this many compiles
+
+
+class _CompileCounter:
+    """jax.monitoring listener: compiles (traces), seconds spent compiling,
+    and when the last ``COMPILE_LOG`` compiles happened (``now()``)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.times: collections.deque = collections.deque(maxlen=COMPILE_LOG)
+
+    def __call__(self, event: str, duration_s: float, **_: Any) -> None:
+        if event not in COMPILE_EVENTS:
+            return
+        with self._lock:
+            self.compile_s += duration_s
+            if event == COMPILE_EVENTS[0]:
+                self.compiles += 1
+                self.times.append(now())
+
+    def stats(self) -> Dict[str, float]:
+        with self._lock:
+            return {"compiles": self.compiles, "compile_s": self.compile_s}
+
+
+_COMPILES = _CompileCounter()
+jax.monitoring.register_event_duration_secs_listener(_COMPILES)
+
+
+def compile_stats() -> Dict[str, float]:
+    """Compiles in this process since it imported this module, and their
+    seconds (trace, lowering and backend compile summed)."""
+    return _COMPILES.stats()
+
+
+def compile_times() -> List[float]:
+    """When each of this process's last ``COMPILE_LOG`` compiles finished its
+    trace, on the program's clock (``now``): the compiles that fell inside a
+    window of the program's own stamps, such as a stretch of slow requests."""
+    with _COMPILES._lock:
+        return list(_COMPILES.times)
