@@ -60,9 +60,9 @@ def run() -> None:
         return jnp.zeros((P, Hkv, page_size, D), jnp.float32).at[
             table.reshape(-1)].set(rows.reshape(B * max_pages, Hkv, page_size, D))
 
-    kp, vp = paged(kc), paged(vc)
+    kp, vp = paged(kc)[None], paged(vc)[None]        # a one-layer pool, layer 0
     lengths = jnp.full((B,), S, jnp.int32)
-    f = jax.jit(lambda q, k, v, t, ln: ref.paged_decode_attention(q, k, v, t, ln))
+    f = jax.jit(lambda q, k, v, t, ln: ref.paged_decode_attention(q, k, v, t, ln, 0))
     dt_paged = _time(f, qd, kp, vp, table, lengths)
     emit("kernel/paged_decode_attention_4k", dt_paged * 1e6,
          f"GBps={gb/dt_paged:.1f};vs_contig={dt_paged/dt:.2f}x")

@@ -54,7 +54,7 @@ def failures(facts: Dict) -> List[str]:
     if facts.get("impl") != "pallas":
         out.append(f"ops resolves to {facts.get('impl')!r}, not 'pallas'")
     for flag in ("tpu_custom_call", "aot_verified", "split_serve",
-                 "decode_aot_verified"):
+                 "decode_aot_verified", "decode_pools_in_place"):
         if facts.get(flag) is not True:
             out.append(f"{flag} is {facts.get(flag)!r}")
     for name in ("prefill_rel_delta", "step_rel_delta"):
@@ -114,6 +114,7 @@ def serve_and_compare(spec, work_dir: str) -> Dict:
         facts["split_serve"] = extra.get("split_serve")
         bundle = gw.decoders[spec.name].bundle
         facts["decode_aot_verified"] = bundle.aot_verified
+        facts["decode_pools_in_place"] = bundle.pools_in_place
         facts["tpu_custom_call"] = "tpu_custom_call" in dep.load_program().as_text()
         print(f"deploy: {spec.name} deploy_s={facts['deploy_s']:.2f} "
               f"build_s={facts['build_s']:.2f} "
@@ -186,8 +187,9 @@ def serve_and_compare(spec, work_dir: str) -> Dict:
         facts["ref_compile_s"] = time.perf_counter() - t0
     logits_r = prefill_ref(params, prompts[0])
     live = spec.batch_size                  # rows _step_inputs admitted
-    step_p = bundle.step(params, *step_args)[0][:live]
+    # the ref first: the deployed step takes the pools donated and deletes them
     step_r = step_ref(params, *step_args)[0][:live]
+    step_p = bundle.step(params, *step_args)[0][:live]
     for name, got, want in (("prefill", logits_p, logits_r),
                             ("step", step_p, step_r)):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)

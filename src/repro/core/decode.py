@@ -28,7 +28,10 @@ submit-time error path); a finished request's pages are released before the
 next admission decision, and admission is deterministic — if the pool cannot
 cover a request's worst case (prompt + max_new), the request WAITS at the
 queue head rather than corrupting a resident chain; the executor is never
-exited while a request is resident.
+exited while a request is resident. Both programs take the device pools
+donated and update them in place, so a program call that raises leaves no
+pools: it fails every resident request and cools the tier, and the next
+request boots fresh.
 
 Tracing: every phase of the loop runs inside a span of
 :data:`repro.core.metrics.SPANS` (submit, admit, boot, step and its inputs,
@@ -357,19 +360,31 @@ class DecodeScheduler:
                 if req.deadline is not None:
                     req.deadline.check("decode-admit")
                 self._ensure_booted(tl)
-                if not tl.t_start_begin:
-                    tl.t_start_begin = tl.t_dispatch
-                tl.t_exec_begin = self._now()
-                page_ids = chain.table_row(self.bundle.max_pages)
-                with span("decode.admit.run"):
-                    logits, self._k_pages, self._v_pages = self._ex.run_decode(
-                        self.bundle.admit, req.tokens, self._k_pages,
-                        self._v_pages, page_ids, timeline=tl)
             except Exception as e:          # noqa: BLE001
                 self.pool.release(chain)
                 if not req.future.done():
                     req.future.set_exception(e)
                 return
+            if not tl.t_start_begin:
+                tl.t_start_begin = tl.t_dispatch
+            tl.t_exec_begin = self._now()
+            page_ids = chain.table_row(self.bundle.max_pages)
+            # the program is given the pools (they are donated): once called,
+            # the arrays held here are gone whether or not it returns
+            k_pages, v_pages = self._k_pages, self._v_pages
+            self._k_pages = self._v_pages = None
+            try:
+                with span("decode.admit.run"):
+                    logits, self._k_pages, self._v_pages = self._ex.run_decode(
+                        self.bundle.admit, req.tokens, k_pages, v_pages,
+                        page_ids, timeline=tl)
+            except Exception as e:
+                self.pool.release(chain)
+                if not req.future.done():
+                    req.future.set_exception(e)
+                # no pools are left to step the residents on: the loop fails
+                # them all and cools the tier, so the next request boots fresh
+                raise
             with span("decode.admit.pull"):
                 tok0 = int(np.argmax(np.asarray(logits, np.float32)))
             self.admits += 1
@@ -397,10 +412,11 @@ class DecodeScheduler:
                     table[i] = a.chain.table_row(mp)
                     pos[i] = a.pos
                     tok[i, 0] = a.toks[-1]
+            k_pages, v_pages = self._k_pages, self._v_pages
+            self._k_pages = self._v_pages = None     # donated to the step
             with span("decode.step.run"):
                 logits, self._k_pages, self._v_pages = self._ex.run_decode(
-                    self.bundle.step, self._k_pages, self._v_pages, table,
-                    pos, tok)
+                    self.bundle.step, k_pages, v_pages, table, pos, tok)
             with span("decode.step.pull"):
                 logits = np.asarray(logits, np.float32)
             self.steps += 1

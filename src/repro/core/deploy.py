@@ -25,6 +25,7 @@ with the scheduler's affinity probes and tier inserts.
 from __future__ import annotations
 
 import dataclasses
+import re
 import threading
 from typing import Any, Callable, Dict, List, Optional
 
@@ -145,6 +146,16 @@ def make_step_fn(model: Model) -> Callable:
     return step
 
 
+def jit_decode_programs(model: Model, max_pages: int, page_size: int):
+    """The admit and step programs, jitted with their K and V pools donated:
+    each output pool is its input's buffer, updated in place, and a call
+    deletes the pools it was given. Returns (admit, step)."""
+    admit = jax.jit(make_admit_fn(model, max_pages, page_size),
+                    donate_argnums=(2, 3))
+    step = jax.jit(make_step_fn(model), donate_argnums=(1, 2))
+    return admit, step
+
+
 @dataclasses.dataclass
 class DecodeBundle:
     """The two fixed-shape programs the decode step loop runs, plus geometry."""
@@ -156,6 +167,23 @@ class DecodeBundle:
     admit: Callable                # (params, tokens[1,S], k, v, ids) -> (logits[V], k, v)
     step: Callable                 # (params, k, v, table, pos, tok) -> (logits[B,V], k, v)
     aot_verified: bool = True      # False: host rejected the blobs, in-process
+    # both programs' output pools alias their donated input pools: a call
+    # updates the pools in place, and deletes the arrays it was given
+    pools_in_place: bool = False
+
+
+# an entry of the HLO module header's input_output_alias={...}:
+# "{out}: (param, {}, may-alias)" for a top-level output and parameter
+_ALIAS_ENTRY = re.compile(r"\{(\d+)\}: \((\d+), \{\}")
+
+
+def pools_alias(compiled) -> bool:
+    """Whether outputs 1 and 2 (the K and V pools of admit and step) alias
+    two distinct parameters in a ``jax.stages.Compiled``'s optimized HLO, so
+    the program updates the pools in place."""
+    header = compiled.as_text().split("\n", 1)[0]
+    aliases = dict(_ALIAS_ENTRY.findall(header))       # output -> parameter
+    return "1" in aliases and "2" in aliases and aliases["1"] != aliases["2"]
 
 
 def first_use_order(fn: Callable, abstract_params: Any, *abstract_args) -> List[str]:
@@ -306,7 +334,10 @@ class Deployment:
         pool of ``n_pages`` pages — so no request ever pays a compile, same
         contract as ``ensure_bucket``. Defaults: ``max_pages`` covers the
         deploy spec's worst case (prompt + decode budget), ``n_pages`` gives
-        every slot a full reservation plus the null page.
+        every slot a full reservation plus the null page. Both programs take
+        the pools donated (``jit_decode_programs``); ``pools_in_place``
+        records that both alias them, and on a TPU a bundle that does not
+        raises.
         """
         if max_pages is None:
             worst = self.spec.prompt_len + self.spec.decode_steps
@@ -317,8 +348,7 @@ class Deployment:
             if self._decode_bundle is not None:
                 return self._decode_bundle
             model = self.model
-            admit_fn = make_admit_fn(model, max_pages, page_size)
-            step_fn = make_step_fn(model)
+            admit_fn, step_fn = jit_decode_programs(model, max_pages, page_size)
             pool = abstract_state(model.page_pool_specs(n_pages, page_size))
             a_kp, a_vp = pool["k_pages"], pool["v_pages"]
             a_tok1 = jax.ShapeDtypeStruct((1, self.spec.prompt_len), jnp.int32)
@@ -326,11 +356,16 @@ class Deployment:
             a_table = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32)
             a_pos = jax.ShapeDtypeStruct((slots,), jnp.int32)
             a_tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
-            admit_c = jax.jit(admit_fn).lower(
+            admit_c = admit_fn.lower(
                 self.abstract_params, a_tok1, a_kp, a_vp, a_ids).compile()
-            step_c = jax.jit(step_fn).lower(
+            step_c = step_fn.lower(
                 self.abstract_params, a_kp, a_vp, a_table, a_pos,
                 a_tok).compile()
+            in_place = pools_alias(admit_c) and pools_alias(step_c)
+            if not in_place:
+                refuse_degrade_on_tpu(
+                    "in-place pools of the decode bundle",
+                    RuntimeError("the admit or step program copies its pools"))
             admit_p, step_p, verified = admit_c, step_c, False
             if self.fallback_program is None:
                 try:
@@ -349,7 +384,7 @@ class Deployment:
             self._decode_bundle = DecodeBundle(
                 slots=slots, page_size=page_size, n_pages=n_pages,
                 max_pages=max_pages, admit=admit_p, step=step_p,
-                aot_verified=verified)
+                aot_verified=verified, pools_in_place=in_place)
             return self._decode_bundle
 
     def load_program(self, bucket_rows: Optional[int] = None) -> Callable:

@@ -82,16 +82,17 @@ def decode_attention(q, k_cache, v_cache, length):
                                interpret=(impl == "interpret"))
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths):
+def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, layer):
     """Single-token attention vs a paged KV cache. q: [B,Hq,D]; pages
-    [P,Hkv,page_size,D]; page_table [B,max_pages] s32; lengths [] or [B]."""
+    [L,P,Hkv,page_size,D]; page_table [B,max_pages] s32; lengths [] or [B];
+    layer [] s32 (the pool read)."""
     impl = _resolved()
     if impl == "ref":
         return ref.paged_decode_attention(q, k_pages, v_pages, page_table,
-                                          lengths)
+                                          lengths, layer)
     from repro.kernels import paged_decode_attention as pda
     return pda.paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                                      interpret=(impl == "interpret"))
+                                      layer, interpret=(impl == "interpret"))
 
 
 def selective_scan(x, dt, a_log, b, c, d_skip, h0=None):

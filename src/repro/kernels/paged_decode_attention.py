@@ -7,20 +7,24 @@ without ever compacting or copying cache memory. This kernel consumes that
 layout directly:
 
     q:          [B, Hq, D]           one query token per sequence (GQA)
-    k_pages:    [P, Hkv, page_size, D]   the shared page pool (head-major)
-    v_pages:    [P, Hkv, page_size, D]
+    k_pages:    [L, P, Hkv, page_size, D]   every layer's page pool (head-major)
+    v_pages:    [L, P, Hkv, page_size, D]
     page_table: [B, max_pages] s32   page ids of each sequence's chain
     lengths:    [B] s32              live positions (0 = empty slot)
+    layer:      [] s32               which layer's pool to read
 
 Grid: (B, Hkv, max_pages) — the page axis innermost and sequential, so the
 online-softmax scratch (m, l, acc) carries across one sequence's page sweep
 exactly like the contiguous kernel. Pages are head-major so each K/V block
 is a [page_size, D] tile in the trailing pair of dims, which is what Mosaic
-tiles. The page table and lengths ride as
-scalar-prefetch operands: each K/V block's HBM address is computed from
-``table[b, ip]`` inside the BlockSpec index_map, so the gather costs no
-host-side copy and touches only the pages a sequence actually owns a table
-entry for. Unused table slots point at page 0 — the pool's reserved null
+tiles. The page table, lengths and layer ride as scalar-prefetch operands:
+each K/V block's HBM address is computed from ``(layer, table[b, ip])``
+inside the BlockSpec index_map, so the gather costs no copy — not of the
+pages, and not of one layer's pool out of the stacked one — and touches
+only the pages a sequence actually owns a table entry for. The step program
+carries the stacked pools through its layer loop and updates them in place;
+a single-layer caller passes ``pool[None]`` and layer 0. Unused table slots
+point at page 0 — the pool's reserved null
 page — whose positions are >= length and die under the score mask; V is
 zeroed under the same mask before the PV dot so whatever the null page holds
 (including NaN) can never ride a 0 * x product into the accumulator.
@@ -39,7 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, page_size: int, n_pages: int):
     b = pl.program_id(0)
     ip = pl.program_id(2)
@@ -51,8 +55,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0, :, :].astype(jnp.float32)                   # [G, D]
-    k = k_ref[0, 0].astype(jnp.float32)                         # [ps, D]
-    v = v_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0, 0].astype(jnp.float32)                      # [ps, D]
+    v = v_ref[0, 0, 0].astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
     length = len_ref[b]
 
@@ -86,33 +90,36 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0, :, :] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
+def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, layer, *,
                            interpret: bool = False):
-    """q: [B, Hq, D]; k_pages, v_pages: [P, Hkv, page_size, D];
-    page_table: [B, max_pages] s32; lengths: [] or [B] s32 -> [B, Hq, D]."""
+    """q: [B, Hq, D]; k_pages, v_pages: [L, P, Hkv, page_size, D];
+    page_table: [B, max_pages] s32; lengths: [] or [B] s32; layer: [] s32
+    -> [B, Hq, D]."""
     B, Hq, D = q.shape
-    _, Hkv, page_size, _ = k_pages.shape
+    _, _, Hkv, page_size, _ = k_pages.shape
     assert Hq % Hkv == 0
     G = Hq // Hkv
     max_pages = page_table.shape[1]
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     page_table = page_table.astype(jnp.int32)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     qg = q.reshape(B, Hkv, G, D)
 
     kernel = functools.partial(_paged_kernel, page_size=page_size,
                                n_pages=max_pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # page_table, lengths
+        num_scalar_prefetch=3,                 # page_table, lengths, layer
         grid=(B, Hkv, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ip, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, D),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, D),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], h, 0, 0)),
+            pl.BlockSpec((1, 1, G, D),
+                         lambda b, h, ip, tbl, ln, ly: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page_size, D),
+                         lambda b, h, ip, tbl, ln, ly: (ly[0], tbl[b, ip], h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page_size, D),
+                         lambda b, h, ip, tbl, ln, ly: (ly[0], tbl[b, ip], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, ip, tbl, ln: (b, h, 0, 0)),
+                               lambda b, h, ip, tbl, ln, ly: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -124,5 +131,5 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(page_table, lengths, qg, k_pages, v_pages)
+    )(page_table, lengths, layer, qg, k_pages, v_pages)
     return out.reshape(B, Hq, D)

@@ -180,24 +180,25 @@ def decode_attention(q, k_cache, v_cache, length, *, block_kv: int = 1024,
     return (acc / l_safe[..., None]).reshape(B, Hq, D).astype(q.dtype)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
+def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, layer, *,
                            block_kv: int = 1024):
     """Single-token attention against a paged KV cache (oracle by gather).
 
-    q: [B, Hq, D]; k_pages, v_pages: [P, Hkv, page_size, D]; page_table:
+    q: [B, Hq, D]; k_pages, v_pages: [L, P, Hkv, page_size, D]; page_table:
     [B, max_pages] s32 (page ids per sequence, unused entries point at the
-    null page 0); lengths: [] or [B] s32. Gathers each sequence's page chain
-    into a contiguous cache and applies the exact contiguous decode math —
-    positions >= length (including everything a null-page entry contributes)
-    are masked there.
+    null page 0); lengths: [] or [B] s32; layer: [] s32. Gathers each
+    sequence's page chain out of ``layer``'s pool into a contiguous cache and
+    applies the exact contiguous decode math — positions >= length (including
+    everything a null-page entry contributes) are masked there.
     """
     B = q.shape[0]
-    _, Hkv, page_size, D = k_pages.shape
+    _, _, Hkv, page_size, D = k_pages.shape
     max_pages = page_table.shape[1]
     table = jnp.asarray(page_table, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
 
     def gather(pages):                  # [B, max_pages, Hkv, ps, D] -> [B, Hkv, S, D]
-        return jnp.swapaxes(pages[table], 1, 2).reshape(
+        return jnp.swapaxes(pages[layer, table], 1, 2).reshape(
             B, Hkv, max_pages * page_size, D)
 
     k, v = gather(k_pages), gather(v_pages)

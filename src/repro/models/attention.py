@@ -128,18 +128,19 @@ def attention_decode(cfg, p: dict, x_t: jax.Array, k_cache: jax.Array,
 
 def attention_decode_paged(cfg, p: dict, x_t: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_table: jax.Array,
-                           pos: jax.Array):
+                           pos: jax.Array, layer: jax.Array):
     """One-token attention against a paged KV cache (continuous batching).
 
-    x_t: [B,1,d]; k_pages/v_pages: [P, nkv, page_size, hd] (the shared pool);
-    page_table: [B, max_pages] s32; pos: [B] s32 per-row positions. Writes
-    each row's new K/V into its chain's page at ``pos`` (empty slots carry an
-    all-null page table, so their writes land on the reserved null page 0),
-    then attends through the page table. Returns (y [B,1,d], k_pages',
-    v_pages').
+    x_t: [B,1,d]; k_pages/v_pages: [L, P, nkv, page_size, hd] (every layer's
+    shared pool); page_table: [B, max_pages] s32; pos: [B] s32 per-row
+    positions; layer: [] s32. Writes each row's new K/V into ``layer``'s pool,
+    in its chain's page at ``pos`` (empty slots carry an all-null page table,
+    so their writes land on the reserved null page 0), with one scatter into
+    the whole pool, then attends through the page table in the same buffer:
+    no layer's pool is sliced out. Returns (y [B,1,d], k_pages', v_pages').
     """
     B = x_t.shape[0]
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[3]
     q = _proj_q(cfg, p, x_t)                                          # [B,1,nq,hd]
     if cfg.rope != "none":
         ppos = _decode_positions(cfg, B, pos)
@@ -150,11 +151,16 @@ def attention_decode_paged(cfg, p: dict, x_t: jax.Array, k_pages: jax.Array,
     rows = jnp.arange(B)
     page = page_table[rows, pos // page_size]                         # [B]
     off = pos % page_size
-    # page and off are split by a slice: the indexed dims lead, [B,nkv,hd]
-    k_pages = k_pages.at[page, :, off].set(k_t[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page, :, off].set(v_t[:, 0].astype(v_pages.dtype))
+    # one index (layer, page, head, off) per row and head, so that each update
+    # is one contiguous [hd] row of the pool's own layout: with a [nkv, hd]
+    # window the TPU compiler lays the pool out page-major for the scatter
+    # and copies all of it to and from the kernel's layout in every layer
+    heads = jnp.arange(k_pages.shape[2])[None, :]
+    page, off = page[:, None], off[:, None]
+    k_pages = k_pages.at[layer, page, heads, off].set(k_t[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, page, heads, off].set(v_t[:, 0].astype(v_pages.dtype))
     o = ops.paged_decode_attention(q[:, 0], k_pages, v_pages, page_table,
-                                   pos + 1)                           # [B,nq,hd]
+                                   pos + 1, layer)                    # [B,nq,hd]
     return _out(cfg, p, o[:, None]), k_pages, v_pages
 
 

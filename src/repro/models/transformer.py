@@ -182,10 +182,10 @@ def uniform_decode(cfg, sp, x_t, cache, pos):
     return x_t, new_cache
 
 
-def _attn_block_decode_paged(cfg, p, x_t, k_pg, v_pg, page_table, pos, cf):
+def _attn_block_decode_paged(cfg, p, x_t, k_pg, v_pg, page_table, pos, layer, cf):
     h = apply_norm(cfg, p["ln1"], x_t)
     a, k_pg, v_pg = attn.attention_decode_paged(cfg, p["attn"], h, k_pg, v_pg,
-                                                page_table, pos)
+                                                page_table, pos, layer)
     x_t = x_t + a
     h2 = apply_norm(cfg, p["ln2"], x_t)
     if "moe" in p:
@@ -202,18 +202,24 @@ def uniform_decode_paged(cfg, sp, x_t, k_pages, v_pages, page_table, pos):
     layer, sharing ONE page table (a logical page spans every layer, so the
     allocator accounts it once). pos: [B] s32 per-row. Unstacked head layers
     (Kimi first-k-dense) keep per-request caches and are not supported here.
+
+    The scan runs over the layer params only; the whole pools ride in its
+    carry, and each layer writes its rows into them and reads them by layer
+    index, so a caller that donates the pools has them updated in place.
     """
     if "head" in sp:
         raise ValueError("paged decode does not support unstacked head layers")
 
-    def body(xx, inp):
-        p_l, k_pg, v_pg = inp
-        xx, k2, v2 = _attn_block_decode_paged(cfg, p_l, xx, k_pg, v_pg,
-                                              page_table, pos, EVAL_CF)
-        return xx, (k2, v2)
+    def body(carry, inp):
+        xx, k_pg, v_pg = carry
+        p_l, layer = inp
+        return _attn_block_decode_paged(cfg, p_l, xx, k_pg, v_pg, page_table,
+                                        pos, layer, EVAL_CF), None
 
-    x_t, (ks, vs) = rscan(body, x_t, (sp["layers"], k_pages, v_pages))
-    return x_t, ks, vs
+    layers = jnp.arange(k_pages.shape[0], dtype=jnp.int32)
+    (x_t, k_pages, v_pages), _ = rscan(body, (x_t, k_pages, v_pages),
+                                       (sp["layers"], layers))
+    return x_t, k_pages, v_pages
 
 
 def uniform_page_pool_specs(cfg, n_pages: int, page_size: int):

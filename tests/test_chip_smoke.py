@@ -17,12 +17,14 @@ import chip_smoke  # noqa: E402
 GOOD = {
     "platform": "tpu", "impl": "pallas", "tpu_custom_call": True,
     "aot_verified": True, "split_serve": True, "decode_aot_verified": True,
+    "decode_pools_in_place": True,
     "prefill_rel_delta": 0.01, "step_rel_delta": 0.01, "request_errors": [],
 }
 BAD = [
     ("platform", "cpu"), ("impl", "ref"), ("impl", "interpret"),
     ("tpu_custom_call", False), ("aot_verified", False), ("split_serve", False),
     ("decode_aot_verified", False), ("aot_verified", None),
+    ("decode_pools_in_place", False),
     ("prefill_rel_delta", 0.5), ("step_rel_delta", 0.5),
     ("prefill_rel_delta", float("nan")), ("step_rel_delta", float("inf")),
     ("step_rel_delta", None), ("request_errors", ["decode request 2: boom"]),

@@ -4,6 +4,15 @@ Token-exactness against the dense per-request decode path, slot backfill and
 occupancy accounting, deterministic FIFO queueing under page exhaustion,
 cool-to-zero with residency accounting, EOS/deadline retirement, and the
 error path that settles every future without killing the loop.
+
+The admit and step programs update the KV page pools in place: they take
+both pools donated and carry them whole through the layer loop, so a call
+deletes the arrays it was given. Covered: the compiled programs alias both
+pools, the AOT-loaded programs delete their inputs, the step computes
+what a non-donating formulation that slices each layer's pool out computes,
+and a program call that raises after consuming the pools fails every
+resident request and cools the tier, so the next request boots fresh and no
+call ever touches a deleted array.
 """
 import dataclasses
 import threading
@@ -18,7 +27,8 @@ import jax.numpy as jnp
 from repro.core import FunctionSpec, Gateway
 from repro.core.decode import DecodeConfig, DecodeScheduler
 from repro.core.paging import PagePool
-from repro.core.resilience import DeadlineExceeded
+from repro.core.resilience import Deadline, DeadlineExceeded
+from repro.models.transformer import EVAL_CF, _attn_block_decode_paged
 
 
 @pytest.fixture(scope="module")
@@ -319,4 +329,167 @@ def test_decode_bundle_is_a_deploy_time_artifact(dgw):
     b2 = dep.ensure_decode(3, 8)
     assert b1 is b2                               # compiled once, ever
     assert b1.aot_verified                        # serialized + reloaded
+    assert b1.pools_in_place                      # both alias both pools
     assert b1.n_pages == 1 + b1.slots * b1.max_pages
+
+
+# ------------------------------------------------------ pools updated in place
+
+def _sliced_step(model, params, k_pages, v_pages, table, pos, token):
+    """A non-donating formulation of the step: the layer scan slices each
+    layer's pool out as ``xs``, writes and attends on that slice alone (as a
+    one-layer pool, layer 0) and stacks the slices back as ``ys``."""
+    cfg = model.cfg
+    x = model._embed(params, {}, token, pos_offset=pos)
+
+    def body(xx, inp):
+        p_l, k_l, v_l = inp
+        xx, k2, v2 = _attn_block_decode_paged(cfg, p_l, xx, k_l[None], v_l[None],
+                                              table, pos, 0, EVAL_CF)
+        return xx, (k2[0], v2[0])
+
+    x, (ks, vs) = jax.lax.scan(body, x, (params["stack"]["layers"], k_pages,
+                                         v_pages))
+    return model._head(params, x)[:, 0], ks, vs
+
+
+def _admitted(dep, bundle, params, n_rows):
+    """Fresh pools with ``n_rows`` prompts admitted through the bundle, and
+    the step inputs (table, pos, token) for every admitted row's next token."""
+    pools = dep.model.init_page_pool(bundle.n_pages, bundle.page_size)
+    k, v = pools["k_pages"], pools["v_pages"]
+    mp = bundle.max_pages
+    table = np.zeros((bundle.slots, mp), np.int32)
+    pos = np.zeros((bundle.slots,), np.int32)
+    tok = np.zeros((bundle.slots, 1), np.int32)
+    for r in range(n_rows):
+        table[r] = 1 + r * mp + np.arange(mp)
+        prompt = dep.example_tokens(seed=r)[:1]
+        logits, k, v = bundle.admit(params, prompt, k, v, table[r])
+        pos[r] = prompt.shape[1]
+        tok[r, 0] = int(np.argmax(np.asarray(logits, np.float32)))
+    return k, v, table, pos, tok
+
+
+def test_a_call_deletes_the_pools_it_was_given(dgw):
+    gw, spec = dgw
+    dep = gw.deployments[spec.name]
+    bundle = dep.ensure_decode(3, 8)
+    params = dep.model.init(jax.random.PRNGKey(spec.seed))
+    pools = dep.model.init_page_pool(bundle.n_pages, bundle.page_size)
+    k, v = pools["k_pages"], pools["v_pages"]
+    table = np.zeros((bundle.slots, bundle.max_pages), np.int32)
+    table[0] = 1 + np.arange(bundle.max_pages)
+    _, k2, v2 = bundle.admit(params, dep.example_tokens()[:1], k, v, table[0])
+    assert k.is_deleted() and v.is_deleted()
+    pos = np.zeros((bundle.slots,), np.int32)
+    pos[0] = spec.prompt_len
+    _, k3, v3 = bundle.step(params, k2, v2, table, pos,
+                            np.zeros((bundle.slots, 1), np.int32))
+    assert k2.is_deleted() and v2.is_deleted()
+    assert not (k3.is_deleted() or v3.is_deleted())
+
+
+def test_step_equals_the_slice_per_layer_formulation(dgw):
+    gw, spec = dgw
+    dep = gw.deployments[spec.name]
+    bundle = dep.ensure_decode(3, 8)
+    model = dep.model
+    params = model.init(jax.random.PRNGKey(spec.seed))
+    k, v, table, pos, tok = _admitted(dep, bundle, params, n_rows=2)
+    want_logits, want_k, want_v = jax.jit(
+        lambda *a: _sliced_step(model, *a))(params, k, v, table, pos, tok)
+    got_logits, got_k, got_v = bundle.step(params, k, v, table, pos, tok)
+    np.testing.assert_array_equal(np.asarray(got_logits[:2], np.float32),
+                                  np.asarray(want_logits[:2], np.float32))
+    # the pools compare at every page the rows own; page 0 takes the empty
+    # slots' writes, in an order neither formulation fixes
+    live = table[:2].reshape(-1)
+    for got, want in ((got_k, want_k), (got_v, want_v)):
+        np.testing.assert_array_equal(np.asarray(got[:, live], np.float32),
+                                      np.asarray(want[:, live], np.float32))
+
+
+def _guarded(fn, touched):
+    """``fn``, recording every call that is handed an array already deleted."""
+    def call(*args, **kw):
+        if any(isinstance(a, jax.Array) and a.is_deleted() for a in args):
+            touched.append(fn)
+        return fn(*args, **kw)
+    return call
+
+
+def test_admit_failure_after_consuming_the_pools_reboots(dgw):
+    """The second admit consumes the pools and then raises: that request
+    fails, and so does the resident one, since no pools are left to step
+    it on; the tier cools, and the next request boots fresh and is served
+    exactly."""
+    gw, spec = dgw
+    dep = gw.deployments[spec.name]
+    sched = DecodeScheduler(dep, gw.cluster, gw.recorder,
+                            DecodeConfig(slots=2, page_size=8,
+                                         cool_after_s=0.1))
+    real = sched.bundle
+    touched = []
+    second_queued = threading.Event()
+    calls = []
+
+    def admit(*a, **k):
+        calls.append(1)
+        if len(calls) == 1:
+            # hold the first admission until the second request is queued,
+            # so the second one's admit runs with the first one resident
+            assert second_queued.wait(60)
+            return real.admit(*a, **k)
+        real.admit(*a, **k)
+        raise RuntimeError("injected admit failure")
+
+    sched.bundle = dataclasses.replace(real, admit=_guarded(admit, touched),
+                                       step=_guarded(real.step, touched))
+    try:
+        resident = sched.submit(dep.example_tokens(seed=1)[:1], max_new=12)
+        failing = sched.submit(dep.example_tokens(seed=2)[:1], max_new=4)
+        second_queued.set()
+        for fut in (failing, resident):
+            with pytest.raises(RuntimeError, match="injected admit failure"):
+                fut.result(300)
+        assert sched.pool.used_pages == 0
+        assert sched.boots == 1
+        sched.bundle = dataclasses.replace(real, admit=_guarded(real.admit, touched),
+                                           step=_guarded(real.step, touched))
+        out = sched.submit(dep.example_tokens(seed=3)[:1], max_new=5).result(300)
+    finally:
+        sched.close()
+    assert out.tolist() == _dense_greedy(dep, dep.example_tokens(seed=3)[:1], 5)
+    assert sched.boots == 2
+    assert touched == []
+
+
+def test_deadline_rejection_at_admit_stays_per_request(dgw):
+    """A request whose deadline passed before its admit fails alone: the
+    pools were never handed to a program, so the resident request runs on."""
+    gw, spec = dgw
+    dep = gw.deployments[spec.name]
+    sched = DecodeScheduler(dep, gw.cluster, gw.recorder,
+                            DecodeConfig(slots=2, page_size=8,
+                                         cool_after_s=0.1))
+    real = sched.bundle
+    late_queued = threading.Event()
+
+    def admit(*a, **k):
+        assert late_queued.wait(60)
+        return real.admit(*a, **k)
+
+    sched.bundle = dataclasses.replace(real, admit=admit)
+    try:
+        resident = sched.submit(dep.example_tokens(seed=4)[:1], max_new=8)
+        late = sched.submit(dep.example_tokens(seed=5)[:1], max_new=4,
+                            deadline=Deadline.after(-1.0))
+        late_queued.set()
+        with pytest.raises(DeadlineExceeded):
+            late.result(300)
+        out = resident.result(300)
+    finally:
+        sched.close()
+    assert out.tolist() == _dense_greedy(dep, dep.example_tokens(seed=4)[:1], 8)
+    assert sched.boots == 1

@@ -11,17 +11,28 @@ The topology is described inside a fixture, never at import: only one process
 may load the TPU library at a time, and every test worker imports this file.
 """
 
+import importlib
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core.compile_cache import persistent_cache_off
+from repro.dist.sharding import abstract_state
+from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.models import build_model
+
+# the module, not the function of the same name that repro.core exports
+deploy = importlib.import_module("repro.core.deploy")
 
 HEADS, HEAD_DIM = 16, 128          # OLMo-1B: MHA, 16 heads of 128
+LAYERS = 16
 PROMPT = 512
 CACHE = 2048
 PAGE_SIZE, SLOTS = 16, 8
@@ -64,9 +75,66 @@ def test_decode_attention_compiles_for_v5e(one_chip, depth):
 
 
 def test_paged_decode_attention_compiles_for_v5e(one_chip):
+    """Every layer's pool stacked, read at a traced layer index."""
     max_pages = CACHE // PAGE_SIZE
-    pages = ((1 + SLOTS * max_pages, HEADS, PAGE_SIZE, HEAD_DIM), jnp.bfloat16)
+    pages = ((LAYERS, 1 + SLOTS * max_pages, HEADS, PAGE_SIZE, HEAD_DIM),
+             jnp.bfloat16)
     text = _compile_text(paged_decode_attention, one_chip,
                          ((SLOTS, HEADS, HEAD_DIM), jnp.bfloat16), pages, pages,
-                         ((SLOTS, max_pages), jnp.int32), ((SLOTS,), jnp.int32))
+                         ((SLOTS, max_pages), jnp.int32), ((SLOTS,), jnp.int32),
+                         ((), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+# the first shape an instruction of these opcodes produces (for copy-start,
+# the first element of its tuple)
+_MOVES = re.compile(r"= \(?(\w+)\[([\d,]*)\]\S* (copy|copy-start|dynamic-slice)\(")
+
+
+def _moved_bytes(hlo_text):
+    """Bytes of the largest buffer a copy or dynamic-slice produces, anywhere
+    in the program (fused computations included)."""
+    largest = 0
+    for dtype, dims, _op in _MOVES.findall(hlo_text):
+        bits = re.search(r"\d+", dtype)          # bf16, f32, s32; pred is a byte
+        n = int(bits.group()) // 8 if bits else 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        largest = max(largest, n)
+    return largest
+
+
+@pytest.mark.parametrize("program", ["admit", "step"])
+def test_decode_programs_update_the_pools_in_place_for_v5e(one_chip, program):
+    """OLMo-1B's admit and step at the benchmark's geometry (32 slots of 8
+    pages of 128 tokens, a 4.3 GB pool pair) alias both donated pools, and no
+    copy or slice anywhere in them moves a buffer as large as one layer's
+    pool; the step keeps no temporary that large either."""
+    slots, page_size, max_pages = 32, 128, 8
+    model = build_model(get_config("olmo-1b"), max_seq=max_pages * page_size)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(abstract_state(model.param_specs()))
+    pool = on_chip(abstract_state(model.page_pool_specs(1 + slots * max_pages,
+                                                        page_size)))
+    k, v = pool["k_pages"], pool["v_pages"]
+    admit, step = deploy.jit_decode_programs(model, max_pages, page_size)
+    with ops.impl_scope("pallas"):
+        if program == "admit":
+            compiled = admit.lower(params, ints(1, 512), k, v,
+                                   ints(max_pages)).compile()
+        else:
+            compiled = step.lower(params, k, v, ints(slots, max_pages),
+                                  ints(slots), ints(slots, 1)).compile()
+    layer_pool = k.size // k.shape[0] * k.dtype.itemsize
+    assert deploy.pools_alias(compiled)
+    assert _moved_bytes(compiled.as_text()) < layer_pool
+    if program == "step":
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
